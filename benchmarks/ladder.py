@@ -21,7 +21,8 @@ import time
 
 sys.path.insert(0, ".")
 
-from p2pnetwork_tpu.utils.jax_env import apply_platform_env  # noqa: E402
+from p2pnetwork_tpu.utils.jax_env import (  # noqa: E402
+    apply_platform_env, enable_compile_cache)
 
 apply_platform_env()
 
@@ -31,8 +32,7 @@ def emit(record):
 
 
 def _sync(stats_entry):
-    """Force device completion via a host transfer (block_until_ready can
-    return early on tunneled backends)."""
+    """Force device completion via a host transfer."""
     return float(stats_entry)
 
 
@@ -576,6 +576,7 @@ def main():
     ap.add_argument("--full", action="store_true",
                     help="include the 10M-node config (long graph build)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     bench_sockets_anchor()
     bench_flood_1k()

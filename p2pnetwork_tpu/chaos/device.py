@@ -100,8 +100,8 @@ class ChipLost(RuntimeError):
 
 class WedgedDispatch(RuntimeError):
     """An injected wedged device dispatch: stands in for the
-    watchdog-detected stall a hung tunnel produces (the real thing hangs
-    holding the GIL — raising is the testable surrogate, the same shape
+    watchdog-detected stall a hung device dispatch produces (the real
+    thing hangs holding the GIL — raising is the testable surrogate, the same shape
     supervise/watchdog.py turns a live stall into)."""
 
     def __init__(self, dispatch_index: int):

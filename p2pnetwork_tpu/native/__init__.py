@@ -5,7 +5,8 @@ graph construction — sorting multi-million-edge lists and deduplicating
 undirected pairs, which dominates wall clock at BASELINE scale when done
 with numpy's comparison sorts. ``graphcore.cpp`` implements them as LSD
 radix passes; this module compiles it on first use (``g++ -O3 -shared``,
-cached next to the source) and binds it with ctypes — no build system, no
+cached next to the source under a name keyed on the source's sha256)
+and binds it with ctypes — no build system, no
 binding generator, and every entry point silently falls back to numpy when
 a compiler is unavailable (``force_fallback()`` pins that for tests).
 
@@ -17,6 +18,7 @@ larger than a reference process would hold sockets.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -41,31 +43,32 @@ def force_fallback(enabled: bool = True) -> None:
     _forced_fallback = enabled
 
 
-def _so_candidates():
-    """Where the compiled library may live: next to the source (dev
-    checkout), else a per-user cache dir (read-only installs)."""
-    yield _SRC.with_name("libgraphcore.so")
+def _so_candidates(digest: str):
+    """Where the library built from the source with this ``digest`` may
+    live: next to the source (dev checkout), else a per-user cache dir
+    (read-only installs). The name carries the digest, so a library built
+    from other source is never loaded, whatever its mtime."""
+    name = f"libgraphcore-{digest}.so"
+    yield _SRC.with_name(name)
     cache = Path(os.environ.get("XDG_CACHE_HOME", Path.home() / ".cache"))
-    yield cache / "p2pnetwork_tpu" / "libgraphcore.so"
+    yield cache / "p2pnetwork_tpu" / name
 
 
 def _compile() -> Optional[Path]:
-    """Compile (or find cached) libgraphcore.so; None means use numpy.
+    """Compile (or find cached) libgraphcore-<digest>.so; None means use
+    numpy.
 
     Every filesystem/toolchain failure is swallowed — the contract of this
     module is a silent numpy fallback, never an import-time crash.
     """
     try:
-        src_mtime = _SRC.stat().st_mtime
+        digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
     except OSError:
         return None  # source not shipped (e.g. a .py-only wheel)
-    for so in _so_candidates():
-        try:
-            if so.exists() and so.stat().st_mtime >= src_mtime:
-                return so
-        except OSError:
-            continue
-    for so in _so_candidates():
+    for so in _so_candidates(digest):
+        if so.exists():
+            return so
+    for so in _so_candidates(digest):
         try:
             so.parent.mkdir(parents=True, exist_ok=True)
             # Build into a temp file then rename: concurrent importers must

@@ -28,10 +28,10 @@ cross-device ``make_async_remote_copy``, so CI proves bit-identity on
 the 8-device virtual mesh without chips; real overlap is a chip-only
 property (the interpreter executes sequentially).
 
-Kernel functions are named ``ring_halo_*`` on purpose: the name lands in
-the ``pallas_call`` eqn's ``name_and_src_info``, which is how the ICI
-accounting recognizes DMA traffic a collective census would otherwise
-read as zero bytes (parallel/commviz.py ``RING_DMA_MARKER``,
+Both ``pallas_call``s are named ``ring_halo_*`` on purpose: the name
+lands in the eqn's ``name`` param, which is how the ICI accounting
+recognizes DMA traffic a collective census would otherwise read as zero
+bytes (parallel/commviz.py ``RING_DMA_MARKER``,
 analysis/ir/registry.py collective census).
 """
 
@@ -69,9 +69,35 @@ def _neighbor(axis_name: str, axis_size: int, reverse: bool):
     return lax.rem(my + 1, axis_size)
 
 
+def _neighbor_barrier(axis_name: str, axis_size: int):
+    """Handshake with both ring neighbours before any remote DMA: signal
+    each one's barrier semaphore and wait for both signals back. A device
+    past the barrier knows the neighbour it writes to has entered the
+    same kernel, so the DMA never lands in a buffer that neighbour has
+    not handed to this call yet (the handshake of JAX's distributed
+    Pallas guide). Compiled kernels only: the CPU interpreter runs the
+    devices one after another, has no barrier semaphore, and cannot
+    race."""
+    barrier = pltpu.get_barrier_semaphore()
+    for reverse in (False, True):
+        pltpu.semaphore_signal(
+            barrier, 1, device_id=_neighbor(axis_name, axis_size, reverse),
+            device_id_type=pltpu.DeviceIdType.LOGICAL)
+    pltpu.semaphore_wait(barrier, 2)
+
+
+def _dma_dtype(dtype):
+    """Mosaic refuses DMAs of bool: such payloads cross as uint8 (same
+    bytes, 0/1) and are cast back after the hop."""
+    return jnp.dtype(jnp.uint8) if dtype == jnp.bool_ else jnp.dtype(dtype)
+
+
 def _ring_halo_copy_kernel(src_ref, dst_ref, send_sem, recv_sem, *,
-                           axis_name: str, axis_size: int, reverse: bool):
+                           axis_name: str, axis_size: int, reverse: bool,
+                           barrier: bool):
     neighbor = _neighbor(axis_name, axis_size, reverse)
+    if barrier:
+        _neighbor_barrier(axis_name, axis_size)
     copy = pltpu.make_async_remote_copy(
         src_ref=src_ref,
         dst_ref=dst_ref,
@@ -89,15 +115,17 @@ def _shift_call(shape, dtype, axis_name: str, axis_size: int, reverse: bool,
                 interpret: bool):
     kernel = functools.partial(
         _ring_halo_copy_kernel, axis_name=axis_name, axis_size=axis_size,
-        reverse=reverse,
+        reverse=reverse, barrier=not interpret,
     )
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(shape, dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA] * 2,
+        compiler_params=pltpu.CompilerParams(collective_id=0),
         interpret=interpret,
+        name=f"{RING_DMA_MARKER}_shift",
     )
 
 
@@ -118,15 +146,17 @@ def ring_shift(x: jax.Array, axis_name: str, axis_size: int, *,
         return x
     if interpret is None:
         interpret = _is_cpu()
-    fn = _shift_call(tuple(x.shape), jnp.dtype(x.dtype).name, axis_name,
-                     axis_size, reverse, interpret)
-    return fn(x)
+    wire = _dma_dtype(x.dtype)
+    fn = _shift_call(tuple(x.shape), wire.name, axis_name, axis_size,
+                     reverse, interpret)
+    return fn(x.astype(wire)).astype(x.dtype)
 
 
 def _ring_halo_segsum_kernel(rot_ref, contrib_ref, dst_ref,
                              rot_out_ref, out_ref, send_sem, recv_sem, *,
                              axis_name: str, axis_size: int,
-                             n_i: int, n_j: int, tile_w: int, precision):
+                             n_i: int, n_j: int, tile_w: int, precision,
+                             barrier: bool):
     """Fused ring step: the halo DMA of the resident block rides UNDER the
     blocked one-hot segment sum. Grid step (0, 0) starts the copy; every
     step accumulates its ``[ROW_TILE, TILE_W]`` strip's partial product
@@ -147,6 +177,8 @@ def _ring_halo_segsum_kernel(rot_ref, contrib_ref, dst_ref,
 
     @pl.when((i == 0) & (j == 0))
     def _():
+        if barrier:
+            _neighbor_barrier(axis_name, axis_size)
         copy.start()
 
     @pl.when(j == 0)
@@ -182,17 +214,18 @@ def _segsum_call(rot_shape, rot_dtype, nb_pad: int, w: int, block: int,
     kernel = functools.partial(
         _ring_halo_segsum_kernel, axis_name=axis_name, axis_size=axis_size,
         n_i=n_i, n_j=n_j, tile_w=tile_w, precision=precision,
+        barrier=not interpret,
     )
     return pl.pallas_call(
         kernel,
         grid=(n_i, n_j),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
             pl.BlockSpec((ROW_TILE, tile_w), lambda i, j: (i, j)),
             pl.BlockSpec((ROW_TILE, tile_w), lambda i, j: (i, j)),
         ],
         out_specs=(
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
             pl.BlockSpec((ROW_TILE, block), lambda i, j: (i, 0)),
         ),
         out_shape=(
@@ -200,7 +233,9 @@ def _segsum_call(rot_shape, rot_dtype, nb_pad: int, w: int, block: int,
             jax.ShapeDtypeStruct((nb_pad, block), jnp.float32),
         ),
         scratch_shapes=[pltpu.SemaphoreType.DMA] * 2,
+        compiler_params=pltpu.CompilerParams(collective_id=0),
         interpret=interpret,
+        name=f"{RING_DMA_MARKER}_segsum",
     )
 
 
@@ -241,8 +276,8 @@ def ring_segment_sum(rot: jax.Array, contrib: jax.Array,
         nb_pad += row_pad
     if interpret is None:
         interpret = _is_cpu()
-    fn = _segsum_call(tuple(rot.shape), jnp.dtype(rot.dtype).name, nb_pad,
-                      w, block, tile_w, axis_name, axis_size, exact,
-                      interpret)
-    rot_next, out = fn(rot, contrib, local_dst)
-    return rot_next, out[:nb]
+    wire = _dma_dtype(rot.dtype)
+    fn = _segsum_call(tuple(rot.shape), wire.name, nb_pad, w, block, tile_w,
+                      axis_name, axis_size, exact, interpret)
+    rot_next, out = fn(rot.astype(wire), contrib, local_dst)
+    return rot_next.astype(rot.dtype), out[:nb]

@@ -90,13 +90,10 @@ def shard_graph_auto(graph: Graph, mesh: Mesh,
     # explicit sharding-in-types (the make_mesh default), a node-sharded
     # gather by edge-sharded indices is a type error instead of an
     # auto-partitioned program.
-    try:
-        mesh = Mesh(
-            mesh.devices, mesh.axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(mesh.axis_names),
-        )
-    except (AttributeError, TypeError):
-        pass  # jax 0.4.x (this image): every mesh axis is Auto already
+    mesh = Mesh(
+        mesh.devices, mesh.axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(mesh.axis_names),
+    )
     spec = NamedSharding(mesh, P(axis_name))
 
     def put(x):

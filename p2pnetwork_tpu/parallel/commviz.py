@@ -144,8 +144,8 @@ def record_traffic(hlo: str, host_of: Callable[[int], int], *,
 # compiles to callbacks (no collective-permute in HLO) — so without this
 # section a Pallas-comm program would read as zero ICI bytes and silently
 # pass every comm budget. The handle is the kernel NAME: every ring-DMA
-# kernel is named ``ring_halo_*`` (pallas_ring.RING_DMA_MARKER), the name
-# lands in the pallas_call eqn's ``name_and_src_info``, and by convention
+# pallas_call is named ``ring_halo_*`` (pallas_ring.RING_DMA_MARKER), the
+# name lands in the eqn's ``name`` param, and by convention
 # the kernel's FIRST output is the DMA payload (the received block), so
 # ``outvars[0]`` prices the hop — one payload copy per hop, the same
 # model a ppermute is priced at.
@@ -183,9 +183,7 @@ def ring_dma_payload_bytes(eqn) -> int:
     Takes a ``jax.core.JaxprEqn`` — jax is imported by the caller."""
     if eqn.primitive.name != "pallas_call":
         return 0
-    name = str(eqn.params.get("name_and_src_info", "")) or str(
-        eqn.params.get("name", ""))
-    if RING_DMA_MARKER not in name:
+    if RING_DMA_MARKER not in str(eqn.params.get("name", "")):
         return 0
     aval = eqn.outvars[0].aval
     import numpy as _np

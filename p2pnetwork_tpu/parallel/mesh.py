@@ -19,12 +19,17 @@ DEFAULT_AXIS = "shards"
 
 def ring_mesh(n_shards: Optional[int] = None, axis_name: str = DEFAULT_AXIS,
               devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
-    """A 1-D mesh of ``n_shards`` devices (default: all local devices)."""
+    """A 1-D mesh of ``n_shards`` devices (default: all local devices).
+
+    Its axis is ``Auto``: the sharded engine places and gathers its
+    arrays by sharding annotations, which ``jax.make_mesh``'s default
+    ``Explicit`` axes would turn into sharding type errors."""
     devs = list(devices) if devices is not None else jax.devices()
     n = n_shards or len(devs)
     if n > len(devs):
         raise ValueError(f"requested {n} shards but only {len(devs)} devices")
-    return jax.make_mesh((n,), (axis_name,), devices=devs[:n])
+    return jax.make_mesh((n,), (axis_name,), devices=devs[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def shard_spec(mesh: Mesh, axis_name: str = DEFAULT_AXIS) -> NamedSharding:

@@ -48,18 +48,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.5 exposes shard_map at the top level
-    shard_map = jax.shard_map
-except AttributeError:  # this image's jax 0.4.x: experimental namespace,
-    # where the replication-check kwarg is still named check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map_exp(f, **kw)
 
 from p2pnetwork_tpu.parallel.mesh import DEFAULT_AXIS
 from p2pnetwork_tpu.sim import flightrec
@@ -1573,8 +1563,8 @@ def _ring_coverage_or(axis_name, S, block, pieces, mxu_block, comm,
     final = jax.lax.while_loop(cond, body, init)
     seen, frontier, rounds, covered, hi, lo, occ = final[:7]
     # One packed i32[5] (replicated) carries the whole summary back — the
-    # engine's single-transfer trick; separate scalars each cost a
-    # device->host round trip on tunneled backends. The fifth slot is the
+    # engine's single-transfer trick; separate scalars would each cost a
+    # device->host round trip. The fifth slot is the
     # mean per-round frontier occupancy (engine _stat_while parity).
     packed = accum.pack_summary(
         rounds, covered / n_live, (hi, lo),
@@ -1833,11 +1823,8 @@ def _ring_rounds_gossip(axis_name, S, block, rng, comm,
         # pcast: a fresh constant is shard-invariant by type; the ring
         # fold adds shard-varying blocks into it, so the accumulator must
         # be marked varying up front (scan carries demand matching vma).
-        # jax 0.4.x (this image) has no vma typing at all — the constant
-        # is already per-shard there, so the cast is an identity.
-        acc0 = jnp.zeros((block,), values.dtype)
-        if hasattr(jax.lax, "pcast"):
-            acc0 = jax.lax.pcast(acc0, (axis_name,), to="varying")
+        acc0 = jax.lax.pcast(jnp.zeros((block,), values.dtype),
+                             (axis_name,), to="varying")
 
         def ring_step(rc, t):
             rot, acc = rc

@@ -385,8 +385,7 @@ def run_until_coverage_from(
     accumulates device-side in a two-limb (hi, lo) counter (utils/accum.py)
     so totals past 2^31 — routine at 10M-node scale — do not wrap int32.
     The whole summary (rounds, coverage, both limbs) comes back in ONE
-    packed transfer — on tunneled backends every extra round trip is
-    milliseconds.
+    packed transfer.
 
     ``donate=True`` (default) hands ``state0``'s buffers to the loop and
     invalidates the caller's copy (see :func:`run_from` for the full

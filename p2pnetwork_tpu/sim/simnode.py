@@ -93,7 +93,7 @@ class JaxSimNode(Node):
                  graph: Optional[Graph] = None, protocol=None, seed: int = 0,
                  mesh=None, dynamic_edges: int = 0, rng: Optional[str] = None,
                  layout: str = "hybrid", adaptive_k: int = 0,
-                 **node_kwargs):
+                 comm: str = "ppermute", **node_kwargs):
         super().__init__(host, port, **node_kwargs)
         self.sim_graph: Optional[Graph] = None
         self.sim_protocol = None
@@ -106,11 +106,13 @@ class JaxSimNode(Node):
         self._sim_rng: Optional[str] = None
         self._sim_key: Optional[jax.Array] = None
         self._sim_adaptive_k = 0
+        self._sim_comm = "ppermute"
         self._churn_count = 0
         if graph is not None and protocol is not None:
             self.attach_simulation(graph, protocol, seed=seed, mesh=mesh,
                                    dynamic_edges=dynamic_edges, rng=rng,
-                                   layout=layout, adaptive_k=adaptive_k)
+                                   layout=layout, adaptive_k=adaptive_k,
+                                   comm=comm)
 
     # ------------------------------------------------------------- plumbing
 
@@ -118,7 +120,8 @@ class JaxSimNode(Node):
                           mesh=None, dynamic_edges: int = 0,
                           rng: Optional[str] = None,
                           layout: str = "hybrid",
-                          adaptive_k: int = 0) -> None:
+                          adaptive_k: int = 0,
+                          comm: str = "ppermute") -> None:
         """Attach (or replace) the simulated population.
 
         ``mesh`` switches the node onto the multi-chip backend
@@ -138,7 +141,9 @@ class JaxSimNode(Node):
         ladder). All layouts are bit-exact. ``adaptive_k > 0`` additionally
         builds the sender-CSR view and runs Flood's ``run_until_coverage``
         through the frontier-adaptive loop (small-frontier rounds skip the
-        ring; bit-identical results).
+        ring; bit-identical results). ``comm`` picks the mesh backend's
+        halo exchange ('ppermute', 'pallas' or 'auto', parallel/auto.py);
+        both backends are bit-identical.
         """
         if layout not in ("hybrid", "mxu", "segment"):
             # Validate regardless of backend: a typo'd layout must not be
@@ -172,6 +177,7 @@ class JaxSimNode(Node):
         self.sim_mesh = mesh
         self._sim_rng = rng
         self._sim_adaptive_k = adaptive_k
+        self._sim_comm = comm
         if mesh is not None:
             from p2pnetwork_tpu.parallel import sharded
 
@@ -225,24 +231,28 @@ class JaxSimNode(Node):
         from p2pnetwork_tpu.parallel import sharded
 
         sg, mesh, proto = self.sim_sharded, self.sim_mesh, self.sim_protocol
+        comm = self._sim_comm
         if isinstance(proto, Flood):
             return sharded.flood(sg, mesh, proto.source, rounds,
-                                 state0=self.sim_state, return_state=True)
+                                 state0=self.sim_state, return_state=True,
+                                 comm=comm)
         if isinstance(proto, SIR):
             return sharded.sir(sg, mesh, proto, seg_key, rounds,
-                               rng=self._sim_rng, status0=self.sim_state)
+                               rng=self._sim_rng, status0=self.sim_state,
+                               comm=comm)
         if isinstance(proto, Gossip):
             return sharded.gossip(sg, mesh, proto, seg_key, rounds,
-                                  rng=self._sim_rng, values0=self.sim_state)
+                                  rng=self._sim_rng, values0=self.sim_state,
+                                  comm=comm)
         if isinstance(proto, HopDistance):
             return sharded.hopdist(sg, mesh, proto, rounds,
-                                   state0=self.sim_state)
+                                   state0=self.sim_state, comm=comm)
         if isinstance(proto, PageRank):
             return sharded.pagerank(sg, mesh, proto, rounds,
-                                    ranks0=self.sim_state)
+                                    ranks0=self.sim_state, comm=comm)
         if isinstance(proto, PushSum):
             return sharded.pushsum(sg, mesh, proto, seg_key, rounds,
-                                   state0=self.sim_state)
+                                   state0=self.sim_state, comm=comm)
         raise ValueError(
             f"the sharded backend implements Flood, SIR, Gossip, "
             f"HopDistance, PageRank and PushSum; got {type(proto).__name__}"
@@ -301,21 +311,21 @@ class JaxSimNode(Node):
                     self.sim_sharded, self.sim_mesh, self.sim_protocol.source,
                     coverage_target=coverage_target, max_rounds=max_rounds,
                     state0=self.sim_state, return_state=True,
-                    adaptive_k=self._sim_adaptive_k,
+                    adaptive_k=self._sim_adaptive_k, comm=self._sim_comm,
                 )
             elif isinstance(self.sim_protocol, HopDistance):
                 self.sim_state, out = sharded.hopdist_until_coverage(
                     self.sim_sharded, self.sim_mesh, self.sim_protocol,
                     coverage_target=coverage_target, max_rounds=max_rounds,
                     state0=self.sim_state,
-                    adaptive_k=self._sim_adaptive_k,
+                    adaptive_k=self._sim_adaptive_k, comm=self._sim_comm,
                 )
             elif isinstance(self.sim_protocol, SIR):
                 self.sim_state, out = sharded.sir_until_coverage(
                     self.sim_sharded, self.sim_mesh, self.sim_protocol,
                     seg_key, coverage_target=coverage_target,
                     max_rounds=max_rounds, rng=self._sim_rng,
-                    status0=self.sim_state,
+                    status0=self.sim_state, comm=self._sim_comm,
                 )
             else:
                 raise ValueError(
@@ -349,13 +359,13 @@ class JaxSimNode(Node):
                 self.sim_state, out = sharded.pagerank_until_residual(
                     self.sim_sharded, self.sim_mesh, self.sim_protocol,
                     tol=threshold, max_rounds=max_rounds,
-                    ranks0=self.sim_state,
+                    ranks0=self.sim_state, comm=self._sim_comm,
                 )
             elif isinstance(self.sim_protocol, PushSum) and stat == "variance":
                 self.sim_state, out = sharded.pushsum_until_variance(
                     self.sim_sharded, self.sim_mesh, self.sim_protocol,
                     seg_key, tol=threshold, max_rounds=max_rounds,
-                    state0=self.sim_state,
+                    state0=self.sim_state, comm=self._sim_comm,
                 )
             else:
                 raise ValueError(

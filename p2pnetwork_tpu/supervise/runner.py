@@ -2,7 +2,7 @@
 
 The engine's run-to-* loops (sim/engine.py) are single device programs —
 maximally fast, and maximally fragile: a preemption or a wedged device
-tunnel mid-run loses everything since the last *manual*
+dispatch mid-run loses everything since the last *manual*
 ``sim/checkpoint.py`` save. :class:`SupervisedRun` drives those same loops
 in round chunks and owns everything around them:
 

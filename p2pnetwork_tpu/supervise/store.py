@@ -101,9 +101,9 @@ class CheckpointStore:
     Single-*process* by design (one supervised run owns one directory),
     but not single-thread: ``emergency_checkpoint`` is documented safe
     from a watchdog ``on_stall`` hook, so the manifest read-modify-write
-    in :meth:`save` is serialized by a lock. Readers (resume, the bench
-    parent publishing a partial record) only ever see complete files
-    because both the entries and the manifest are rename-published.
+    in :meth:`save` is serialized by a lock. Readers (resume) only ever
+    see complete files because both the entries and the manifest are
+    rename-published.
     """
 
     def __init__(self, directory: str, *, retain: int = 3,

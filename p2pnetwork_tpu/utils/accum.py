@@ -52,8 +52,7 @@ def value(acc: Acc) -> int:
 def pack_summary(rounds: jax.Array, coverage: jax.Array, acc: Acc,
                  extra=None) -> jax.Array:
     """[rounds, coverage-bits, hi, lo-bits] as one i32[4] — a single
-    device->host transfer carries a whole run summary (on tunneled
-    backends every extra round trip is milliseconds). Shared by the
+    device->host transfer carries a whole run summary. Shared by the
     engine's and the sharded path's run-to-coverage loops.
 
     ``extra`` (optional f32 scalar) appends a fifth slot — the engine
@@ -100,8 +99,7 @@ def pack_batch_summary(rounds: jax.Array, active_lanes: jax.Array,
     ``done`` lane flags packed as ``u32[W]`` words (ops/bitset.py lane
     order) and each lane's applied-round count ``i32[B]``. One packed
     vector = one device->host transfer for the whole B-message summary,
-    however many messages rode the batch (on tunneled backends every
-    extra round trip is milliseconds — B of them would dwarf the run)."""
+    however many messages rode the batch."""
     hi, lo = acc
     head = jnp.stack([
         rounds.astype(jnp.int32),

@@ -15,7 +15,7 @@ Covers the PR-12 observability plane end to end:
   the ``/history`` + ``/trace`` endpoints (incl. an N-thread concurrent
   scrape hammer and a graftrace-seam scrape storm);
 - satellites: Prometheus label/help escaping pin, jaxhooks install
-  idempotence, bench probe_log + profiler bracket.
+  idempotence, bench profiler bracket.
 """
 
 import json
@@ -920,69 +920,6 @@ class TestJaxhooksIdempotence:
         jax.jit(lambda x: x * 2.5 - 3)(
             jnp.arange(17, dtype=jnp.float32)).block_until_ready()
         assert twice.value("jax_compiles_total") == n_twice
-
-
-class TestBenchProbeLog:
-    def test_backend_alive_records_structured_probe_log(self, monkeypatch):
-        import bench
-
-        monkeypatch.setattr(bench, "_PROBE_LOG", [])
-        monkeypatch.setattr(bench, "_probe_backend_once",
-                            lambda t: "backend init timed out (wedged?)")
-        monkeypatch.setattr(bench.time, "sleep", lambda s: None)
-        err = bench._backend_alive(window_s=300, probe_timeout_s=1,
-                                   max_attempts=2)
-        assert err is not None and "gave up" in err
-        log = bench._PROBE_LOG
-        fails = [e for e in log if "error" in e]
-        assert [e["attempt"] for e in fails] == [1, 2]
-        assert all("wedged" in e["error"] for e in fails)
-        assert any("gave_up" in e for e in log)
-        json.dumps(log)  # artifact-ready
-
-    def test_recovery_recorded(self, monkeypatch):
-        import bench
-
-        monkeypatch.setattr(bench, "_PROBE_LOG", [])
-        outcomes = iter(["wedged once", None])
-        monkeypatch.setattr(bench, "_probe_backend_once",
-                            lambda t: next(outcomes))
-        monkeypatch.setattr(bench.time, "sleep", lambda s: None)
-        assert bench._backend_alive(window_s=300, probe_timeout_s=1,
-                                    max_attempts=3) is None
-        kinds = [("recovered" if e.get("recovered") else "error")
-                 for e in bench._PROBE_LOG
-                 if not e.get("policy_summary")]  # graftsight's trailer
-        assert kinds == ["error", "recovered"]
-
-    def test_probe_log_lands_in_telemetry_artifact(self, tmp_path,
-                                                   monkeypatch,
-                                                   fresh_registry):
-        import bench
-
-        parent_log = [{"attempt": 1, "error": "wedged tunnel",
-                       "window_remaining_s": 100.0}]
-        # isolate from probes other tests ran in this process
-        monkeypatch.setattr(bench, "_PROBE_LOG", [])
-        monkeypatch.setenv("BENCH_TELEMETRY_DIR", str(tmp_path))
-        # the parent's probes arrive via the env seam _stage_in_child sets
-        monkeypatch.setenv("BENCH_PROBE_LOG", json.dumps(parent_log))
-        bench._write_stage_telemetry("1m", {}, 0.0)
-        doc = json.load(open(tmp_path / "BENCH_TELEMETRY.json",
-                             encoding="utf-8"))
-        assert doc["probe_log"] == parent_log
-
-    def test_clean_round_has_empty_probe_log(self, tmp_path, monkeypatch,
-                                             fresh_registry):
-        import bench
-
-        monkeypatch.setattr(bench, "_PROBE_LOG", [])
-        monkeypatch.delenv("BENCH_PROBE_LOG", raising=False)
-        monkeypatch.setenv("BENCH_TELEMETRY_DIR", str(tmp_path))
-        bench._write_stage_telemetry("1m", {}, 0.0)
-        doc = json.load(open(tmp_path / "BENCH_TELEMETRY.json",
-                             encoding="utf-8"))
-        assert doc["probe_log"] == []
 
 
 class TestBenchProfileBracket:

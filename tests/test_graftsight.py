@@ -666,48 +666,6 @@ class TestEngineBatchSummaryEvent:
         assert ev.parent_id == run.span_id
 
 
-class TestBenchProbePolicySummary:
-    def test_gave_up_session_summarized(self, monkeypatch):
-        import bench
-        monkeypatch.setattr(bench, "_PROBE_LOG", [])
-        monkeypatch.setattr(bench, "_probe_backend_once",
-                            lambda t: "wedged")
-        monkeypatch.setattr(bench.time, "sleep", lambda s: None)
-        err = bench._backend_alive(window_s=300, probe_timeout_s=1,
-                                   max_attempts=2)
-        assert err is not None
-        (summary,) = [e for e in bench._PROBE_LOG
-                      if e.get("policy_summary")]
-        assert summary["outcome"] == "gave_up"
-        assert summary["attempts"] == 2
-        assert len(summary["backoff_schedule_s"]) == 2
-        # The schedule IS the attempts' recorded backoffs (satellite 3:
-        # replayable from the artifact alone).
-        logged = [e["backoff_s"] for e in bench._PROBE_LOG
-                  if "backoff_s" in e]
-        assert logged == summary["backoff_schedule_s"]
-        json.dumps(bench._PROBE_LOG)
-
-    def test_healed_and_clean_outcomes(self, monkeypatch):
-        import bench
-        monkeypatch.setattr(bench, "_PROBE_LOG", [])
-        monkeypatch.setattr(bench.time, "sleep", lambda s: None)
-        outcomes = iter(["wedged once", None])
-        monkeypatch.setattr(bench, "_probe_backend_once",
-                            lambda t: next(outcomes))
-        assert bench._backend_alive(window_s=300, probe_timeout_s=1,
-                                    max_attempts=3) is None
-        (summary,) = [e for e in bench._PROBE_LOG
-                      if e.get("policy_summary")]
-        assert summary["outcome"] == "healed" and summary["attempts"] == 2
-        bench._PROBE_LOG.clear()
-        monkeypatch.setattr(bench, "_probe_backend_once", lambda t: None)
-        assert bench._backend_alive(window_s=300, probe_timeout_s=1) is None
-        (summary,) = [e for e in bench._PROBE_LOG
-                      if e.get("policy_summary")]
-        assert summary["outcome"] == "clean" and summary["attempts"] == 1
-
-
 # ------------------------------------------------------ overhead ratchet
 
 

@@ -105,7 +105,7 @@ class TestJaxprRules:
     def test_f64_widen_fires_at_the_lowering_name(self):
         def build():
             def bad(x):
-                with jax.experimental.enable_x64():
+                with jax.enable_x64(True):
                     y = x.astype(jnp.float64) * 2.0
                 return y.astype(jnp.float32)
             return bad, (_sig(),)

@@ -17,6 +17,26 @@ def test_native_library_compiles_and_loads():
     assert native.available(), "g++ is in this image; the library must build"
 
 
+def test_library_is_keyed_on_the_source_hash(tmp_path, monkeypatch):
+    # A library built from other source is never loaded, however new its
+    # mtime: the name carries the source's digest, so an edited
+    # graphcore.cpp looks for (and builds) a library of its own.
+    src = tmp_path / "graphcore.cpp"
+    src.write_bytes(native._SRC.read_bytes())
+    monkeypatch.setattr(native, "_SRC", src)
+    monkeypatch.setenv("XDG_CACHE_HOME", str(tmp_path / "cache"))
+    stale = tmp_path / "libgraphcore.so"
+    stale.write_bytes(b"not a library")
+    built = native._compile()
+    assert built is not None and built != stale
+    assert built.parent == tmp_path and built.name.startswith(
+        "libgraphcore-")
+    assert native._compile() == built  # found again, not rebuilt
+    src.write_bytes(src.read_bytes() + b"\n// edited\n")
+    rebuilt = native._compile()
+    assert rebuilt is not None and rebuilt != built
+
+
 class TestSortPairs:
     @pytest.mark.parametrize("n", [0, 1, 7, 1000, 100_000])
     def test_matches_numpy_stable_argsort(self, n):

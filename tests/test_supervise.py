@@ -1,5 +1,5 @@
 """Supervised execution plane: watchdogs, checkpoint store, crash-tolerant
-runs, preemption faults, deadline-bounded shutdown, bench partial records.
+runs, preemption faults, deadline-bounded shutdown.
 
 The crash-recovery core is proven two ways: fast in-process tests drive the
 deterministic ``preempt`` fault (a SIGKILL stand-in at an exact round), and
@@ -665,65 +665,6 @@ class TestNodeStopDeadline:
             assert reg.value("p2p_shutdown_undelivered_total", node="a") == 0
         finally:
             stop_all([a, b])
-
-
-# --------------------------------------------------- bench partial records
-
-
-class TestBenchPartialRecord:
-    def _bench_env(self, tmp_path, **extra):
-        env = dict(os.environ)
-        env.update({
-            "JAX_PLATFORMS": "cpu",
-            "BENCH_N_1M": "2000",
-            "BENCH_N_10M": "3000",
-            "BENCH_BACKEND_WINDOW_S": "5",
-            "BENCH_PROBE_TIMEOUT_S": "60",
-            "BENCH_CACHE_DIR": str(tmp_path / "cache"),
-            "BENCH_TELEMETRY_DIR": str(tmp_path),
-            "BENCH_SUPERVISE_CHUNK": "1",
-        })
-        env.update({k: str(v) for k, v in extra.items()})
-        return env
-
-    def test_dead_stage_publishes_partial_resumed_record(self, tmp_path):
-        # The stage child SIGKILLs itself mid-supervised-pass (the
-        # deterministic stand-in for a mid-run wedge/preemption): the
-        # parent must publish a partial record tagged backend=resumed with
-        # rounds-completed and a checkpoint path, not drop the stage.
-        env = self._bench_env(tmp_path, BENCH_SUPERVISE_KILL_AT_ROUND="2")
-        r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                           env=env, capture_output=True, text=True,
-                           timeout=600, cwd=REPO)
-        assert r.returncode == 0, r.stderr[-2000:]
-        rec = json.loads(
-            [ln for ln in r.stdout.splitlines() if ln.strip()][-1])
-        assert rec["backend"] == "resumed"
-        assert rec["rounds_completed"] >= 2
-        assert os.path.exists(rec["checkpoint_path"])
-        assert "error" in rec
-        artifact_path = tmp_path / "BENCH_TELEMETRY.json"
-        assert artifact_path.exists()
-        artifact = json.loads(artifact_path.read_text())
-        assert artifact["partial"] is True
-        assert artifact["backend"] == "resumed"
-        assert artifact["rounds_completed"] == rec["rounds_completed"]
-
-        # Second run, kill seam disarmed: the supervised pass RESUMES the
-        # trail (no restart from round 0) and the stage completes with a
-        # real measured headline.
-        env2 = self._bench_env(tmp_path)
-        r2 = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                            env=env2, capture_output=True, text=True,
-                            timeout=600, cwd=REPO)
-        assert r2.returncode == 0, r2.stderr[-2000:]
-        rec2 = json.loads(
-            [ln for ln in r2.stdout.splitlines() if ln.strip()][-1])
-        assert rec2["value"] is not None and rec2["value"] > 0
-        assert rec2.get("backend") != "resumed"
-        artifact2 = json.loads(artifact_path.read_text())
-        sup = artifact2["supervised"]
-        assert sup["resumed_from"] >= 2  # continued, not restarted
 
 
 # --------------------------------------- SIGKILL crash-recovery subprocess
