@@ -4281,6 +4281,7 @@ def run_batch_until_coverage(sg: ShardedGraph, mesh: Mesh, protocol,
             _engine._emit_batch_exit_events(admitted0, done0, out)
         # One summary-bridging site (engine's): shared sim_* counters under
         # loop="batch", batch gauges/histograms, occupancy recency pruning.
-        _engine._record_batch_summary(t2 - t0, t2 - t1, nbytes, out,
-                                      newly_rounds, type(protocol).__name__)
+        _engine._record_batch_summary(t2 - t0, t1 - t0, t2 - t1, nbytes,
+                                      out, newly_rounds,
+                                      type(protocol).__name__)
     return batch, out

@@ -42,7 +42,8 @@ existing planes into one stateful process:
   round, queue order, seed): quota buckets refill per tick, not per
   wall-second; ticket ids are a persisted counter; records store ticks
   and rounds, never wall timestamps (wall-clock latency lives only in
-  the ``serve_latency_seconds`` histogram) — so a seeded traffic replay
+  the ``serve_latency_seconds`` and ``serve_queue_wait_seconds``
+  histograms) — so a seeded traffic replay
   (serve/traffic.py) produces byte-identical per-ticket summaries.
 
 Threading: control-plane state (tickets, queue, quotas, counters) is
@@ -140,19 +141,24 @@ def _delta_from_fields(rec: dict) -> "graph_mod.GraphDelta":
 class _PhaseClock:
     """Per-tick wall breakdown of the serve driver into the
     :data:`TICK_PHASES`. Always measures (``time.perf_counter`` deltas
-    — a handful of clock reads per tick); additionally emits a
+    — a handful of clock reads per tick) and marks the tick and each
+    phase on the profiler timeline under the same names
+    (:func:`spans.profiler_annotation`); additionally emits a
     ``serve_tick`` span with nested per-phase child spans when a tracer
     is installed. Wall times feed metrics/spans ONLY — never ticket
     records — so the serving plane's determinism contract holds with
     the profiler permanently on."""
 
-    __slots__ = ("phases", "_t0", "_name", "_tracer", "_tick_sid", "_sid")
+    __slots__ = ("phases", "_t0", "_name", "_tracer", "_tick_sid", "_sid",
+                 "_tick_mark", "_mark")
 
     def __init__(self, tracer):
         self._tracer = tracer
         self._tick_sid = tracer.begin("serve_tick") \
             if tracer is not None else None
-        self._sid = None
+        self._tick_mark = spans.profiler_annotation("serve_tick")
+        self._tick_mark.__enter__()
+        self._sid = self._mark = None
         self._name: Optional[str] = None
         self.phases: Dict[str, float] = {}
         self._t0 = time.perf_counter()
@@ -161,6 +167,9 @@ class _PhaseClock:
         if self._name is not None:
             self.phases[self._name] = (
                 self.phases.get(self._name, 0.0) + (now - self._t0))
+        if self._mark is not None:
+            self._mark.__exit__(None, None, None)
+            self._mark = None
         if self._sid is not None:
             self._tracer.end(self._sid)
             self._sid = None
@@ -171,11 +180,14 @@ class _PhaseClock:
         if self._tracer is not None:
             self._sid = self._tracer.begin(f"tick_{name}",
                                            parent=self._tick_sid)
+        self._mark = spans.profiler_annotation(f"tick_{name}")
+        self._mark.__enter__()
         self._name, self._t0 = name, now
 
     def done(self, tick: int) -> Dict[str, float]:
         self._close_phase(time.perf_counter())
         self._name = None
+        self._tick_mark.__exit__(None, None, None)
         if self._tick_sid is not None:
             self._tracer.end(self._tick_sid)
             self._tracer.point(
@@ -640,6 +652,10 @@ class SimService:
         self._m_latency_s = reg.histogram(
             "serve_latency_seconds",
             "Submit-to-completion wall latency per completed ticket.")
+        self._m_queue_wait_s = reg.histogram(
+            "serve_queue_wait_seconds",
+            "Submit-to-admission wall time per ticket: the wait in the "
+            "FIFO until the driver's admit phase gives it a lane.")
         self._m_phase = reg.histogram(
             "serve_tick_phase_seconds",
             "Per-tick wall time of each driver phase (graftsight "
@@ -679,6 +695,10 @@ class SimService:
         self._phase_totals: Dict[str, float] = {}
         self._phase_max: Dict[str, float] = {}
         self._phase_ticks = 0
+        # serve_queue_wait_seconds' count and sum for THIS service (the
+        # histogram may be shared through the default registry).
+        self._queue_waits = 0
+        self._queue_wait_s = 0.0
 
         self._store: Optional[CheckpointStore] = None
         self._journal: Optional[Journal] = None
@@ -1586,13 +1606,18 @@ class SimService:
     def tick_phases(self) -> dict:
         """The tick-phase profile (graftsight): ``{"ticks", "per_phase":
         {phase: {"total_s", "mean_s", "last_s", "max_s"}}, "recent":
-        [last 32 per-tick rows]}``. Thread-safe — what ``/dashboard``
-        and the bench ``serving.tick_phases`` slice read."""
+        [last 32 per-tick rows], "queue_wait": {"count", "total_s"}}``,
+        the last the tickets the admit phase has given a lane and their
+        summed submit-to-lane wall (``serve_queue_wait_seconds``).
+        Thread-safe — what ``/dashboard`` and the bench
+        ``serving.tick_phases`` slice read."""
         with self._phase_lock:
             ticks = self._phase_ticks
             totals = dict(self._phase_totals)
             maxes = dict(self._phase_max)
             recent = list(self._phase_ring[-32:])
+            queue_wait = {"count": self._queue_waits,
+                          "total_s": self._queue_wait_s}
         per_phase = {}
         for ph in TICK_PHASES:
             tot = totals.get(ph, 0.0)
@@ -1602,7 +1627,8 @@ class SimService:
                 "last_s": recent[-1][ph] if recent else 0.0,
                 "max_s": maxes.get(ph, 0.0),
             }
-        return {"ticks": ticks, "per_phase": per_phase, "recent": recent}
+        return {"ticks": ticks, "per_phase": per_phase, "recent": recent,
+                "queue_wait": queue_wait}
 
     def dashboard_slice(self) -> dict:
         """What ``/dashboard`` embeds for this service (duck-typed by
@@ -1629,6 +1655,7 @@ class SimService:
             self._batch, lanes = self._protocol.admit(
                 self.graph, self._batch, sources, coverage_target=target)
             assigned.extend(zip(lanes.tolist(), [tid for tid, _ in entries]))
+        admitted_at = time.perf_counter()
         # Lanes whose SEED already meets the target start done at
         # admission (tiny coverage targets, near-single-node graphs).
         # The engine excludes pre-run-done lanes from
@@ -1642,6 +1669,8 @@ class SimService:
             if (self._record_seen_hash and instant) else {}
         completions: List[Tuple[str, dict]] = []
         with self._cond:
+            # Read, not popped: completion pops it for serve_latency_seconds.
+            submitted_at = [self._submit_walls.get(tid) for _, tid in assigned]
             for lane, tid in assigned:
                 rec = self._tickets.get(tid)
                 if rec is None:
@@ -1677,6 +1706,13 @@ class SimService:
                      for tid, _ in completions]
             if completions:
                 self._cond.notify_all()  # graftlint: ignore[lock-open-call] -- Condition.notify_all/wait REQUIRE holding the condition's own lock (stdlib contract); wait releases it while blocked
+        waits = [admitted_at - t_sub for t_sub in submitted_at
+                 if t_sub is not None]
+        for w in waits:
+            self._m_queue_wait_s.observe(w)
+        with self._phase_lock:
+            self._queue_waits += len(waits)
+            self._queue_wait_s += sum(waits)
         if spans.current_tracer() is not None:
             for lane, tid in assigned:
                 spans.emit("ticket_admit", trace=ticket_trace(tid),

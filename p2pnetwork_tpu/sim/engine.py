@@ -42,7 +42,12 @@ graftscope rides the resume/batch/query loops: ``recorder=`` on
 bounded per-round record ring INSIDE the compiled carry — donated like
 the state, bit-identical results, one extra fetch per run — and, when a
 trace plane is installed (telemetry/spans.py), batched runs emit
-``batch_run`` spans with per-lane lifecycle events. Run summaries also
+``batch_run`` spans with per-lane lifecycle events. Each run-to-* call
+splits into a ``run_dispatch`` span (entry until the jitted loop call
+returns: stats check, retrace, lowering, compile or cache load, launch;
+``sim_dispatch_seconds_total{loop}``) and a ``run_summary`` span (the
+one blocking transfer; ``sim_transfer_seconds_total``), both on the
+profiler timeline too. Run summaries also
 sample the default history ring (telemetry/history.py) so ``/history``
 serves per-run gauge series with zero extra wiring.
 """
@@ -116,8 +121,8 @@ def _observe_occupancy(loop: str, protocol_name: str, value: float) -> None:
             hist.remove(*coldest)
 
 
-def _record_run_summary(loop: str, wall_s: float, transfer_s: float,
-                        transfer_bytes: int, out: dict,
+def _record_run_summary(loop: str, wall_s: float, dispatch_s: float,
+                        transfer_s: float, transfer_bytes: int, out: dict,
                         protocol_name: str = "") -> None:
     """Bridge one host-side run summary into the registry post-transfer.
 
@@ -125,6 +130,10 @@ def _record_run_summary(loop: str, wall_s: float, transfer_s: float,
     their entry and the packed-summary transfer, so that is where the
     telemetry plane observes the sim backend."""
     reg = telemetry.default_registry()
+    reg.counter("sim_dispatch_seconds_total",
+                "Host seconds from a run-to-* entry until its jitted loop "
+                "call returned: stats check, retrace, lowering, compile or "
+                "cache load, launch.", ("loop",)).labels(loop).inc(dispatch_s)
     reg.counter("sim_runs_total", "Completed run-to-* loop invocations.",
                 ("loop",)).labels(loop).inc()
     reg.counter("sim_rounds_total", "Protocol rounds executed on device.",
@@ -163,20 +172,24 @@ def _timed_summary(loop: str, t0: float, state, packed,
     protocols without the stat, which must not pollute the histogram).
     ``ring`` is the flight-recorder carry when the run recorded one —
     fetched in the SAME blocking ``device_get`` as the summary (still
-    one sync point per run) and attached as ``out["flight_record"]``."""
+    one sync point per run) and attached as ``out["flight_record"]``.
+    ``t0`` opened the caller's ``run_dispatch`` span: ``t1 - t0`` is its
+    dispatch, ``t2 - t1`` this ``run_summary`` span's transfer."""
     t1 = time.perf_counter()
-    nbytes = sum(int(getattr(leaf, "nbytes", 0))
-                 for leaf in jax.tree_util.tree_leaves((packed, ring)))
-    if ring is not None:
-        packed, ring = jax.device_get((packed, ring))
-    out = _unpack_summary(packed)
+    with spans.span("run_summary", loop=loop):
+        nbytes = sum(int(getattr(leaf, "nbytes", 0))
+                     for leaf in jax.tree_util.tree_leaves((packed, ring)))
+        if ring is not None:
+            packed, ring = jax.device_get((packed, ring))
+        out = _unpack_summary(packed)
     extra = out.pop("extra", None)
     if has_occupancy and extra is not None:
         out["frontier_occupancy_mean"] = extra
     if ring is not None:
         out["flight_record"] = flightrec.trim(ring, out["rounds"])
     t2 = time.perf_counter()
-    _record_run_summary(loop, t2 - t0, t2 - t1, nbytes, out, protocol_name)
+    _record_run_summary(loop, t2 - t0, t1 - t0, t2 - t1, nbytes, out,
+                        protocol_name)
     history.default_history().sample()
     return state, out
 
@@ -351,13 +364,15 @@ def run_until_coverage(
     ``frontier_occupancy_mean`` and recorded into the
     ``sim_frontier_occupancy`` histogram.
     """
-    keys = _require_stats(graph, protocol, None, key, ("coverage", "messages"))
     t0 = time.perf_counter()
-    state, packed = _coverage_with_init(
-        graph, protocol, key,
-        coverage_target=coverage_target, max_rounds=max_rounds,
-        steps_per_round=steps_per_round,
-    )
+    with spans.span("run_dispatch", loop="coverage"):
+        keys = _require_stats(graph, protocol, None, key,
+                              ("coverage", "messages"))
+        state, packed = _coverage_with_init(
+            graph, protocol, key,
+            coverage_target=coverage_target, max_rounds=max_rounds,
+            steps_per_round=steps_per_round,
+        )
     return _timed_summary("coverage", t0, state, packed,
                           type(protocol).__name__,
                           "frontier_occupancy" in keys)
@@ -404,27 +419,29 @@ def run_until_coverage_from(
     # is touched — one attribute read + None check when nothing is
     # installed (chaos/device.py).
     chaos_device.dispatch_gate("engine-coverage")
-    keys = _require_stats(graph, protocol, state0, key,
-                          ("coverage", "messages"))
     t0 = time.perf_counter()
-    if recorder is None:
-        loop_fn = _pick_loop(_coverage_loop_donating, _coverage_loop_keeping,
-                             donate, state0, graph, key)
-        state, packed = loop_fn(
-            graph, protocol, state0, key,
-            coverage_target=coverage_target, max_rounds=max_rounds,
-            steps_per_round=steps_per_round,
-        )
-        ring = None
-    else:
-        loop_fn = _pick_loop(_coverage_loop_rec_donating,
-                             _coverage_loop_rec_keeping, donate, state0,
-                             graph, key)
-        state, packed, ring = loop_fn(
-            graph, protocol, state0, key, recorder.init(),
-            coverage_target=coverage_target, max_rounds=max_rounds,
-            steps_per_round=steps_per_round,
-        )
+    with spans.span("run_dispatch", loop="coverage_from"):
+        keys = _require_stats(graph, protocol, state0, key,
+                              ("coverage", "messages"))
+        if recorder is None:
+            loop_fn = _pick_loop(_coverage_loop_donating,
+                                 _coverage_loop_keeping, donate, state0,
+                                 graph, key)
+            state, packed = loop_fn(
+                graph, protocol, state0, key,
+                coverage_target=coverage_target, max_rounds=max_rounds,
+                steps_per_round=steps_per_round,
+            )
+            ring = None
+        else:
+            loop_fn = _pick_loop(_coverage_loop_rec_donating,
+                                 _coverage_loop_rec_keeping, donate, state0,
+                                 graph, key)
+            state, packed, ring = loop_fn(
+                graph, protocol, state0, key, recorder.init(),
+                coverage_target=coverage_target, max_rounds=max_rounds,
+                steps_per_round=steps_per_round,
+            )
     return _timed_summary("coverage_from", t0, state, packed,
                           type(protocol).__name__,
                           "frontier_occupancy" in keys, ring=ring)
@@ -463,15 +480,17 @@ def run_until_converged(
 
     ``donate=True`` (default) hands a non-None ``state0``'s buffers to the
     loop and invalidates the caller's copy (see :func:`run_from`)."""
-    keys = _require_stats(graph, protocol, state0, key, (stat, "messages"))
     t0 = time.perf_counter()
-    loop_fn = _pick_loop(_converged_loop_donating,
-                         _converged_loop_keeping, donate, state0, graph,
-                         key)
-    state, packed = loop_fn(
-        graph, protocol, state0, key, stat=stat, threshold=threshold,
-        max_rounds=max_rounds, steps_per_round=steps_per_round,
-    )
+    with spans.span("run_dispatch", loop="converged"):
+        keys = _require_stats(graph, protocol, state0, key,
+                              (stat, "messages"))
+        loop_fn = _pick_loop(_converged_loop_donating,
+                             _converged_loop_keeping, donate, state0, graph,
+                             key)
+        state, packed = loop_fn(
+            graph, protocol, state0, key, stat=stat, threshold=threshold,
+            max_rounds=max_rounds, steps_per_round=steps_per_round,
+        )
     state, out = _timed_summary("converged", t0, state, packed,
                                 type(protocol).__name__,
                                 "frontier_occupancy" in keys)
@@ -602,8 +621,8 @@ _batch_loop_rec_keeping = functools.partial(  # graftlint: ignore[carry-no-donat
     jax.jit, static_argnames=("protocol", "max_rounds"))(_batch_loop_rec)
 
 
-def _record_batch_summary(wall_s: float, transfer_s: float,
-                          transfer_bytes: int, out: dict,
+def _record_batch_summary(wall_s: float, dispatch_s: float,
+                          transfer_s: float, transfer_bytes: int, out: dict,
                           newly_done_rounds, protocol_name: str) -> None:
     """Bridge one batched run summary into the registry: the shared
     sim_* run counters under ``loop="batch"`` plus the batch plane's own
@@ -615,8 +634,8 @@ def _record_batch_summary(wall_s: float, transfer_s: float,
     # The shared sim_* run counters register through the one site that
     # owns their names/help/labels (loop="batch" has no "coverage" key,
     # so the coverage gauge and occupancy branches there stay idle).
-    _record_run_summary("batch", wall_s, transfer_s, transfer_bytes, out,
-                        protocol_name)
+    _record_run_summary("batch", wall_s, dispatch_s, transfer_s,
+                        transfer_bytes, out, protocol_name)
     reg = telemetry.default_registry()
     reg.gauge("sim_batch_active_lanes",
               "Lanes still running (admitted, not at target) when the last "
@@ -774,8 +793,8 @@ def run_batch_until_coverage(graph: Graph, protocol, batch, key: jax.Array,
                        active_lanes=int(out["active_lanes"]),
                        newly_completed=int(
                            out["newly_completed_lanes"].size))
-        _record_batch_summary(t2 - t0, t2 - t1, nbytes, out, newly_rounds,
-                              type(protocol).__name__)
+        _record_batch_summary(t2 - t0, t1 - t0, t2 - t1, nbytes, out,
+                              newly_rounds, type(protocol).__name__)
     return state, out
 
 
@@ -870,8 +889,8 @@ _query_loop_rec_keeping = functools.partial(  # graftlint: ignore[carry-no-donat
     jax.jit, static_argnames=("protocol", "max_rounds"))(_query_loop_rec)
 
 
-def _record_query_summary(wall_s: float, transfer_s: float,
-                          transfer_bytes: int, out: dict,
+def _record_query_summary(wall_s: float, dispatch_s: float,
+                          transfer_s: float, transfer_bytes: int, out: dict,
                           newly_done_rounds, protocol_name: str) -> None:
     """Bridge one batched query-run summary into the registry: the
     shared sim_* run counters under ``loop="query"`` plus the query
@@ -879,8 +898,8 @@ def _record_query_summary(wall_s: float, transfer_s: float,
     running at return: >0 means max_rounds froze stragglers) and one
     ``sim_query_completion_rounds`` observation per lane that settled
     in this call."""
-    _record_run_summary("query", wall_s, transfer_s, transfer_bytes, out,
-                        protocol_name)
+    _record_run_summary("query", wall_s, dispatch_s, transfer_s,
+                        transfer_bytes, out, protocol_name)
     reg = telemetry.default_registry()
     reg.gauge("sim_query_active_lanes",
               "Query lanes still running (admitted, not settled) when "
@@ -982,8 +1001,8 @@ def run_queries_until_done(graph: Graph, protocol, batch, key: jax.Array,
                 np.percentile(newly_rounds, 99))
         if tracer is not None:
             _emit_batch_exit_events(admitted0, done0, out)
-        _record_query_summary(t2 - t0, t2 - t1, nbytes, out, newly_rounds,
-                              type(protocol).__name__)
+        _record_query_summary(t2 - t0, t1 - t0, t2 - t1, nbytes, out,
+                              newly_rounds, type(protocol).__name__)
     return state, out
 
 

@@ -18,15 +18,18 @@ observe the simulation with no new API.
 
 from __future__ import annotations
 
+import time
 from typing import Any, Optional
 
 import jax
 import numpy as np
 
+from p2pnetwork_tpu import telemetry
 from p2pnetwork_tpu.node import Node
 from p2pnetwork_tpu.sim import checkpoint as ckpt
 from p2pnetwork_tpu.sim import engine
 from p2pnetwork_tpu.sim.graph import Graph
+from p2pnetwork_tpu.telemetry import spans
 
 
 class SimPeer:
@@ -144,6 +147,9 @@ class JaxSimNode(Node):
         ring; bit-identical results). ``comm`` picks the mesh backend's
         halo exchange ('ppermute', 'pallas' or 'auto', parallel/auto.py);
         both backends are bit-identical.
+
+        The key and the initial state are built under a ``sim_attach``
+        span; their host seconds add to ``sim_attach_seconds_total``.
         """
         if layout not in ("hybrid", "mxu", "segment"):
             # Validate regardless of backend: a typo'd layout must not be
@@ -171,26 +177,34 @@ class JaxSimNode(Node):
                     f"adaptive_k applies to Flood and HopDistance on the "
                     f"mesh backend; got {type(protocol).__name__}"
                 )
-        self.sim_graph = graph
-        self.sim_protocol = protocol
-        self._sim_key = jax.random.key(seed)
-        self.sim_mesh = mesh
-        self._sim_rng = rng
-        self._sim_adaptive_k = adaptive_k
-        self._sim_comm = comm
-        if mesh is not None:
-            from p2pnetwork_tpu.parallel import sharded
+        t0 = time.perf_counter()
+        with spans.span("sim_attach"):
+            self.sim_graph = graph
+            self.sim_protocol = protocol
+            self._sim_key = jax.random.key(seed)
+            self.sim_mesh = mesh
+            self._sim_rng = rng
+            self._sim_adaptive_k = adaptive_k
+            self._sim_comm = comm
+            if mesh is not None:
+                from p2pnetwork_tpu.parallel import sharded
 
-            sg = sharded.shard_graph(graph, mesh, mxu=layout == "mxu",
-                                     hybrid=layout == "hybrid",
-                                     source_csr=adaptive_k > 0)
-            if dynamic_edges:
-                sg = sharded.with_capacity(sg, dynamic_edges)
-            self.sim_sharded = sg
-            self.sim_state = sharded.init_state(sg, protocol, self._sim_key)
-        else:
-            self.sim_sharded = None
-            self.sim_state = protocol.init(graph, self._sim_key)
+                sg = sharded.shard_graph(graph, mesh, mxu=layout == "mxu",
+                                         hybrid=layout == "hybrid",
+                                         source_csr=adaptive_k > 0)
+                if dynamic_edges:
+                    sg = sharded.with_capacity(sg, dynamic_edges)
+                self.sim_sharded = sg
+                self.sim_state = sharded.init_state(sg, protocol,
+                                                    self._sim_key)
+            else:
+                self.sim_sharded = None
+                self.sim_state = protocol.init(graph, self._sim_key)
+        telemetry.default_registry().counter(
+            "sim_attach_seconds_total",
+            "Host seconds in JaxSimNode.attach_simulation: the run key, "
+            "the protocol's initial state (and the shard on a mesh)."
+        ).inc(time.perf_counter() - t0)
         self.sim_round = 0
         self.sim_message_count = 0
         self._churn_count = 0
@@ -297,48 +311,55 @@ class JaxSimNode(Node):
         """Device-side run-to-coverage continuing from the current state
         (no per-round events; one summary ``node_message`` at the end).
         On the mesh backend this is the multi-chip while_loop
-        (sharded.flood_until_coverage / sharded.sir_until_coverage)."""
+        (sharded.flood_until_coverage / sharded.sir_until_coverage). The
+        call runs under a ``sim_run`` span holding the engine's
+        ``run_dispatch`` / ``run_summary`` spans."""
         self._require_sim()
-        seg_key = jax.random.fold_in(self._sim_key, self.sim_round)
-        if self.sim_mesh is not None:
-            from p2pnetwork_tpu.models.flood import Flood
-            from p2pnetwork_tpu.models.hopdist import HopDistance
-            from p2pnetwork_tpu.models.sir import SIR
-            from p2pnetwork_tpu.parallel import sharded
+        with spans.span("sim_run"):
+            seg_key = jax.random.fold_in(self._sim_key, self.sim_round)
+            if self.sim_mesh is not None:
+                from p2pnetwork_tpu.models.flood import Flood
+                from p2pnetwork_tpu.models.hopdist import HopDistance
+                from p2pnetwork_tpu.models.sir import SIR
+                from p2pnetwork_tpu.parallel import sharded
 
-            if isinstance(self.sim_protocol, Flood):
-                self.sim_state, out = sharded.flood_until_coverage(
-                    self.sim_sharded, self.sim_mesh, self.sim_protocol.source,
-                    coverage_target=coverage_target, max_rounds=max_rounds,
-                    state0=self.sim_state, return_state=True,
-                    adaptive_k=self._sim_adaptive_k, comm=self._sim_comm,
-                )
-            elif isinstance(self.sim_protocol, HopDistance):
-                self.sim_state, out = sharded.hopdist_until_coverage(
-                    self.sim_sharded, self.sim_mesh, self.sim_protocol,
-                    coverage_target=coverage_target, max_rounds=max_rounds,
-                    state0=self.sim_state,
-                    adaptive_k=self._sim_adaptive_k, comm=self._sim_comm,
-                )
-            elif isinstance(self.sim_protocol, SIR):
-                self.sim_state, out = sharded.sir_until_coverage(
-                    self.sim_sharded, self.sim_mesh, self.sim_protocol,
-                    seg_key, coverage_target=coverage_target,
-                    max_rounds=max_rounds, rng=self._sim_rng,
-                    status0=self.sim_state, comm=self._sim_comm,
-                )
+                if isinstance(self.sim_protocol, Flood):
+                    self.sim_state, out = sharded.flood_until_coverage(
+                        self.sim_sharded, self.sim_mesh,
+                        self.sim_protocol.source,
+                        coverage_target=coverage_target,
+                        max_rounds=max_rounds,
+                        state0=self.sim_state, return_state=True,
+                        adaptive_k=self._sim_adaptive_k, comm=self._sim_comm,
+                    )
+                elif isinstance(self.sim_protocol, HopDistance):
+                    self.sim_state, out = sharded.hopdist_until_coverage(
+                        self.sim_sharded, self.sim_mesh, self.sim_protocol,
+                        coverage_target=coverage_target,
+                        max_rounds=max_rounds,
+                        state0=self.sim_state,
+                        adaptive_k=self._sim_adaptive_k, comm=self._sim_comm,
+                    )
+                elif isinstance(self.sim_protocol, SIR):
+                    self.sim_state, out = sharded.sir_until_coverage(
+                        self.sim_sharded, self.sim_mesh, self.sim_protocol,
+                        seg_key, coverage_target=coverage_target,
+                        max_rounds=max_rounds, rng=self._sim_rng,
+                        status0=self.sim_state, comm=self._sim_comm,
+                    )
+                else:
+                    raise ValueError(
+                        "run_until_coverage on the sharded backend "
+                        "implements Flood, SIR and HopDistance; the protocol "
+                        "must expose a coverage stat"
+                    )
             else:
-                raise ValueError(
-                    "run_until_coverage on the sharded backend implements "
-                    "Flood, SIR and HopDistance; the protocol must expose "
-                    "a coverage stat"
+                self.sim_state, out = engine.run_until_coverage_from(
+                    self.sim_graph, self.sim_protocol, self.sim_state,
+                    seg_key, coverage_target=coverage_target,
+                    max_rounds=max_rounds,
                 )
-        else:
-            self.sim_state, out = engine.run_until_coverage_from(
-                self.sim_graph, self.sim_protocol, self.sim_state, seg_key,
-                coverage_target=coverage_target, max_rounds=max_rounds,
-            )
-        return self._finish_run(out)
+            return self._finish_run(out)
 
     def run_until_converged(self, stat: str, threshold: float,
                             max_rounds: int = 1024) -> dict:

@@ -9,7 +9,11 @@ registries as
 
 - ``jax_compiles_total`` — backend-compile count (a recompile detector:
   a loop whose shapes churn shows this climbing per call),
-- ``jax_compile_seconds_total{stage=...}`` — wall time per lowering stage.
+- ``jax_compile_seconds_total{stage=...}`` — wall time per lowering stage,
+- ``jax_cache_load_seconds_total`` — wall time reading executables back
+  from the persistent compilation cache (``/jax/compilation_cache/
+  cache_retrieval_time_sec``: a cache hit replaces the backend compile
+  with this load).
 
 ``jax.monitoring`` has no per-listener unregister (only a global
 ``clear_event_listeners``), so ONE process-wide listener is installed on
@@ -33,10 +37,11 @@ _registries: set = set()
 _listener_registered = False
 
 _BACKEND_COMPILE = "backend_compile"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 def _on_event_duration(event: str, duration: float, **kw) -> None:
-    if "/compile/" not in event:
+    if "/compile/" not in event and event != _CACHE_RETRIEVAL:
         return
     stage = event.rsplit("/", 1)[-1]
     if stage.endswith("_duration"):
@@ -49,6 +54,13 @@ def _on_event_duration(event: str, duration: float, **kw) -> None:
     # swaps the default registry keeps receiving compile events.
     targets = {default_registry() if r is None else r for r in subscribed}
     for reg in targets:
+        if event == _CACHE_RETRIEVAL:
+            reg.counter(
+                "jax_cache_load_seconds_total",
+                "Wall seconds loading executables from the persistent "
+                "compilation cache.",
+            ).inc(duration)
+            continue
         reg.counter(
             "jax_compile_seconds_total",
             "Wall seconds spent in jit lowering/compilation, by stage.",

@@ -13,10 +13,10 @@ structured records, and XLA-level profiler capture.
   total wall time. All rounds execute inside one ``lax.scan``, so there is
   no per-round wall clock — stats are computed on device and tracing adds
   one transfer at the end, not one per round.
-- :func:`annotate` — name a region so it shows up in profiler timelines
-  (``jax.profiler.TraceAnnotation``).
 - :func:`profile` — capture an XLA profile (TensorBoard format) around a
-  block, via ``jax.profiler.trace``.
+  block, via ``jax.profiler.trace``. Regions are named on its timeline by
+  ``telemetry.spans.span`` (its profiler mirror); :func:`run_traced`'s
+  run is a ``run_traced`` span.
 """
 
 from __future__ import annotations
@@ -30,14 +30,7 @@ import jax
 import numpy as np
 
 from p2pnetwork_tpu import telemetry
-from p2pnetwork_tpu.telemetry import jaxhooks
-
-
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Name the enclosed device work in profiler timelines."""
-    with jax.profiler.TraceAnnotation(name):
-        yield
+from p2pnetwork_tpu.telemetry import jaxhooks, spans
 
 
 @contextlib.contextmanager
@@ -87,7 +80,7 @@ def run_traced(
     ctx = profile(profile_dir) if profile_dir else contextlib.nullcontext()
     t0 = time.perf_counter()
     with ctx:
-        with annotate(f"{label}:rounds={rounds}"):
+        with spans.span("run_traced", label=label, rounds=rounds):
             state, stats = engine.run(graph, protocol, key, rounds)
             jax.block_until_ready(stats)
     wall_s = time.perf_counter() - t0

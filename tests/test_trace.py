@@ -105,7 +105,9 @@ def test_profile_capture(tmp_path, graph):
     assert captured, "no profile artifacts captured"
 
 
-def test_annotate_is_transparent(graph):
-    with trace.annotate("custom-step"):
+def test_span_mirror_is_transparent(graph):
+    from p2pnetwork_tpu.telemetry import spans
+
+    with spans.span("custom-step"):
         out = jax.numpy.sum(jax.numpy.arange(8))
     assert int(out) == 28
