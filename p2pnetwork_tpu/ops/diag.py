@@ -48,17 +48,21 @@ class HybridEdges:
 
     ``masks[d, v]`` is True iff the edge ``(v + offsets[d]) mod n -> v``
     exists. ``remainder`` holds every other edge in blocked form (None when
-    the diagonals cover the whole graph).
+    the diagonals cover the whole graph). ``n_diag_edges`` is the number
+    of edges the diagonals took at build time (static, so ``auto`` can
+    read it while tracing; node failures re-mask without changing it).
+    ``remainder_kernel`` names the remainder lowering the layout supports:
+    ``"pallas"``, or ``"blocked"`` once placed on a GSPMD mesh
+    (parallel/auto.py), where a ``pallas_call`` cannot be partitioned.
     """
 
     masks: jax.Array  # bool[D, n] (D may be 0)
     remainder: Optional[BlockedEdges]
     offsets: Tuple[int, ...] = dataclasses.field(metadata=dict(static=True))
     n: int = dataclasses.field(metadata=dict(static=True))
-
-    @property
-    def n_diag_edges(self) -> int:
-        return int(self.masks.sum()) if len(self.offsets) else 0
+    n_diag_edges: int = dataclasses.field(metadata=dict(static=True))
+    remainder_kernel: str = dataclasses.field(
+        default="pallas", metadata=dict(static=True))
 
 
 def build_hybrid(
@@ -207,6 +211,7 @@ def build_hybrid_from_arrays(
         remainder=remainder,
         offsets=offsets,
         n=n,
+        n_diag_edges=int(sum(sel.size for sel in per_sel)),
     )
 
 

@@ -27,6 +27,24 @@ jit and gathers only their out-edge rows through the source-CSR view,
 falling back to the dense path via ``lax.cond`` when the active count
 exceeds the crossover budget — the fast path for the sparse first/last
 rounds of a flood. Requires ``from_edges(source_csr=True)``.
+
+What ``auto`` picks for an OR aggregation (``_auto_or_method``): on a TPU,
+the hybrid diagonal + Pallas lowering (ops/diag.py) when the graph carries
+a hybrid layout whose circular diagonals hold at least half of its edges
+(``_HYBRID_DIAG_SHARE``; ~90% on Watts-Strogatz at p = 0.1, almost none on
+ER/BA); otherwise, and on every other backend, the table / skew / segment
+routing of ``_auto_method``. On one v5e, a 1M-node WS flood (k = 10,
+p = 0.1) to 99% ran 0.1111 s a flood through ``hybrid`` against 1.4728 s
+through the table gather, in the same 11 rounds with the same seen set,
+and compiled in 1.4 s against 218 s. In the benchmark's ``ws-1m.flood``
+cell (one v5e, a new source per flood through ``JaxSimNode``) the route
+took a flood from 2.10-2.12 s to 0.33-0.35 s and a round's device time
+from ~133 ms to 9.8 ms, with every flood equal to the BFS. Sum, max and
+min-plus keep ``_auto_method``: max and min-plus have no hybrid form, and
+a float sum through the diagonals adds in another order. OR over 0/1 is
+exact in any order. Each resolution counts into
+``aggregation_route_total{op="or", method}`` at trace time, so the counter
+counts traced programs, not rounds.
 """
 
 from __future__ import annotations
@@ -36,6 +54,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from p2pnetwork_tpu import telemetry
 from p2pnetwork_tpu.sim.graph import Graph
 
 
@@ -46,8 +65,18 @@ from p2pnetwork_tpu.sim.graph import Graph
 #: widens EVERY row. Measured on chip (single-source flood to 99%):
 #: WS 1M @ 1.7x waste — gather 1.53 s vs segment 1.87 s (gather wins);
 #: ER 100K @ 2.5x — 0.142 vs 0.163 s (gather wins); BA 100K @ 178x —
-#: 3.97 vs 0.12 s (gather loses 33x). Break-even sits near 3-4x.
+#: 3.97 vs 0.12 s (gather loses 33x). Break-even sits near 3-4x. For OR
+#: on a TPU the hybrid lowering outranks the gather where the graph
+#: carries one (``_auto_or_method``): on the 1M WS flood the gather costs
+#: ~133 ms a round and the hybrid ~10 ms.
 _GATHER_WASTE_BOUND = 4.0
+
+#: ``auto`` routes an OR aggregation to ``hybrid`` on a TPU only when the
+#: layout's diagonals hold at least this share of the graph's edges: the
+#: shifts then carry most of the round and the Pallas remainder the rest.
+#: WS at p = 0.1 keeps ~90% on its diagonals; ER and BA keep almost none,
+#: since a diagonal needs ``max(n // 256, 128)`` edges to be kept.
+_HYBRID_DIAG_SHARE = 0.5
 
 
 def _gather_ok(graph: Graph) -> bool:
@@ -67,6 +96,27 @@ def _auto_method(graph: Graph) -> str:
     if graph.skew is not None:
         return "skew"
     return "segment"
+
+
+def _auto_or_method(graph: Graph, backend: str | None = None) -> str:
+    """``auto``'s routing for OR: ``hybrid`` on a TPU when the graph's
+    hybrid layout keeps its Pallas remainder (not placed on a mesh) and
+    its diagonals hold at least ``_HYBRID_DIAG_SHARE`` of the edges;
+    ``_auto_method`` otherwise. ``backend`` defaults to JAX's."""
+    hybrid = graph.hybrid
+    if (hybrid is not None and hybrid.remainder_kernel == "pallas"
+            and hybrid.n_diag_edges
+            >= _HYBRID_DIAG_SHARE * max(graph.n_edges, 1)
+            and (backend or jax.default_backend()) == "tpu"):
+        return "hybrid"
+    return _auto_method(graph)
+
+
+def _route_counter():
+    return telemetry.default_registry().counter(
+        "aggregation_route_total",
+        "Lowerings auto resolved, by aggregation; counted at trace time, "
+        "so once per traced program.", ("op", "method"))
 
 
 def _require_skew(graph: Graph) -> None:
@@ -111,14 +161,20 @@ def propagate_or(graph: Graph, signal: jax.Array, method: str = "auto", *,
     """Per-node OR over incoming neighbors: ``out[v] = any(signal[u], u->v)``.
 
     ``signal`` is bool[N_pad]; masked (padding) edges and nodes contribute
-    nothing. ``method`` is ``"segment"``, ``"gather"`` or ``"auto"``
-    (gather when the graph carries a complete neighbor table whose
-    padding waste stays under ``_GATHER_WASTE_BOUND`` — degree-skewed
-    tables route to segment). Dynamic edges (sim/topology.py) are folded
-    in for every method. ``frontier_crossover`` overrides the
-    ``method="frontier"`` sparse budget (ops/frontier.py ``budget``:
-    float = fraction of padded nodes, int = node budget) — the supported
-    "apply" step for a crossover re-fit from measured occupancy.
+    nothing. ``method`` is ``"segment"``, ``"gather"``, ``"skew"``,
+    ``"blocked"``, ``"pallas"``, ``"hybrid"``, ``"hybrid-blocked"``,
+    ``"frontier"`` or ``"auto"``. ``auto`` takes ``hybrid`` on a TPU
+    when the graph's hybrid layout holds at least half its edges on
+    diagonals (11 rounds of a 1M WS flood: 0.1111 s against the gather's
+    1.4728 s on one v5e); else gather when the graph carries a complete
+    neighbor table whose padding waste stays under
+    ``_GATHER_WASTE_BOUND``; degree-skewed tables route to skew or
+    segment. Every choice is exact for OR. Dynamic edges
+    (sim/topology.py) are folded in for every method.
+    ``frontier_crossover`` overrides the ``method="frontier"`` sparse
+    budget (ops/frontier.py ``budget``: float = fraction of padded
+    nodes, int = node budget) — the supported "apply" step for a
+    crossover re-fit from measured occupancy.
     """
     if graph.dyn_senders is not None:
         static = dataclasses.replace(graph, dyn_senders=None,
@@ -133,7 +189,8 @@ def propagate_or(graph: Graph, signal: jax.Array, method: str = "auto", *,
             graph, signal, lambda sig: propagate_or(graph, sig, "auto"),
             crossover=frontier_crossover)
     if method == "auto":
-        method = _auto_method(graph)
+        method = _auto_or_method(graph)
+        _route_counter().labels(op="or", method=method).inc()
     if method == "gather":
         _require_complete_table(graph)
         vals = signal[graph.neighbors] & graph.neighbor_mask

@@ -124,7 +124,8 @@ def shard_graph_auto(graph: Graph, mesh: Mesh,
         # shard axis 1 when it divides. The remainder rides the blocked
         # (einsum) form — under this path use method="hybrid-blocked";
         # the Pallas remainder kernel is an opaque custom call the
-        # partitioner cannot shard.
+        # partitioner cannot shard. The mark keeps ``auto`` off it: OR
+        # routes as it would without the layout (ops/segment.py).
         if hybrid is None:
             return None
         div = hybrid.masks.shape[1] % axis_size == 0
@@ -133,6 +134,7 @@ def shard_graph_auto(graph: Graph, mesh: Mesh,
             hybrid,
             masks=jax.device_put(hybrid.masks, mspec),
             remainder=put_blocked(hybrid.remainder),
+            remainder_kernel="blocked",
         )
 
     def put_skew(skew):

@@ -592,11 +592,13 @@ def load_graph(path: str):
                     mask=jnp.asarray(data["hybrid_rem_mask"]),
                     block=int(meta["hybrid_rem_block"]),
                 )
+            masks = data["hybrid_masks"]
             hybrid = HybridEdges(
-                masks=jnp.asarray(data["hybrid_masks"]),
+                masks=jnp.asarray(masks),
                 remainder=rem,
                 offsets=tuple(meta["hybrid_offsets"]),
                 n=int(meta["hybrid_n"]),
+                n_diag_edges=int(masks.sum()),
             )
         cap = meta.get("max_degree_cap")  # absent in pre-cap files
         return Graph(

@@ -1,7 +1,7 @@
 """chip_smoke.py's phases at small n on the CPU, and its refusals.
 
 The phases are the ones the chip runs at 1M nodes: the NumPy BFS reference
-against the engine (hybrid and auto), SimService tickets against the
+against the engine (hybrid and gather), SimService tickets against the
 reference, and the 4-ring under both halo backends against the single
 device. ``main()`` itself must refuse any platform but a TPU.
 """
@@ -31,10 +31,10 @@ def _checks(capsys):
 
 def test_engine_matches_bfs_reference(capsys):
     out = chip_smoke.phase_main(3000, require_kernel=False)
-    assert set(out) == {"hybrid", "auto"}
-    assert out["hybrid"] == out["auto"]
+    assert set(out) == {"hybrid", "gather"}
+    assert out["hybrid"] == out["gather"]
     checks = _checks(capsys)
-    for method in ("hybrid", "auto"):
+    for method in ("hybrid", "gather"):
         line = checks[f"flood_{method}"]
         assert line["equals_bfs"] is True and line["rounds"] >= 1
 

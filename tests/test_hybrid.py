@@ -147,3 +147,113 @@ def test_self_loops_do_not_displace_diagonals():
     h = build_hybrid_from_arrays(s[order], r[order], n, n,
                                  max_diags=2, min_count=16)
     assert sorted(h.offsets) == [1, 2]
+
+
+# -- auto's OR route ---------------------------------------------------------
+
+def _ws(hybrid):
+    return G.watts_strogatz(2048, 10, 0.1, seed=0, hybrid=hybrid)
+
+
+def _ba_hybrid():
+    return G.barabasi_albert(2048, 3, seed=0, hybrid=True)
+
+
+def _ws_on_mesh():
+    from p2pnetwork_tpu.parallel import auto
+    from p2pnetwork_tpu.parallel import mesh as M
+
+    if len(jax.devices()) < 8:
+        pytest.skip("needs 8 host devices")
+    g = auto.shard_graph_auto(_ws(True), M.ring_mesh(8))
+    assert g.hybrid.remainder_kernel == "blocked"
+    return g
+
+
+# (make graph, aggregation, backend, lowering auto must resolve to).
+_ROUTES = {
+    "ws_hybrid_tpu": (lambda: _ws(True), "or", "tpu", "hybrid"),
+    "ws_plain_tpu": (lambda: _ws(False), "or", "tpu", "gather"),
+    "ba_hybrid_tpu": (_ba_hybrid, "or", "tpu", "segment"),
+    "ws_hybrid_cpu": (lambda: _ws(True), "or", "cpu", "gather"),
+    "ba_hybrid_cpu": (_ba_hybrid, "or", "cpu", "segment"),
+    "ring_hybrid_cpu": (lambda: G.ring(1024, hybrid=True), "or", "cpu",
+                        "gather"),
+    "ws_mesh_tpu": (_ws_on_mesh, "or", "tpu", "gather"),
+    "ws_hybrid_tpu_sum": (lambda: _ws(True), "sum", "tpu", "gather"),
+    "ws_hybrid_tpu_max": (lambda: _ws(True), "max", "tpu", "gather"),
+    "ws_hybrid_tpu_min_plus": (lambda: _ws(True), "min_plus", "tpu",
+                               "gather"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ROUTES))
+def test_auto_route(case, monkeypatch):
+    """OR takes the hybrid lowering only on a TPU, only when the layout's
+    diagonals hold at least half the edges, and never for a layout placed
+    on a mesh; every other graph, backend and aggregation routes as
+    ``_auto_method`` always has."""
+    make, op, backend, expect = _ROUTES[case]
+    g = make()
+    share = (0.0 if g.hybrid is None
+             else g.hybrid.n_diag_edges / g.n_edges)
+    if case.startswith("ws_hybrid"):
+        assert share > 0.8
+    if case.startswith("ba_hybrid"):
+        assert share < segment._HYBRID_DIAG_SHARE
+    if op == "or":
+        assert segment._auto_or_method(g, backend=backend) == expect
+        if expect != "hybrid":
+            assert segment._auto_method(g) == expect
+        return
+    # Sum, max and min-plus never consult the backend: on a (faked) TPU
+    # their auto program is still the one of the lowering _auto_method names.
+    assert segment._auto_method(g) == expect
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    fn = {"sum": segment.propagate_sum, "max": segment.propagate_max,
+          "min_plus": segment.propagate_min_plus}[op]
+    x = jnp.zeros(g.n_nodes_padded, jnp.float32)
+
+    def program(method):
+        return str(jax.make_jaxpr(lambda v: fn(g, v, method))(x))
+
+    assert program("auto") == program(expect)
+
+
+def _route_count(method):
+    from p2pnetwork_tpu import telemetry
+
+    return telemetry.default_registry().value(
+        "aggregation_route_total", op="or", method=method)
+
+
+def test_auto_route_counter_counts_traced_programs():
+    g = _ws(True)
+    fn = jax.jit(lambda s: segment.propagate_or(g, s, "auto"))
+    sig = jnp.zeros(g.n_nodes_padded, dtype=bool).at[3].set(True)
+    before = _route_count("gather")
+    fn(sig)
+    fn(sig)  # the same program again: no new trace, no new count
+    assert _route_count("gather") == before + 1
+
+
+def test_forced_hybrid_flood_equals_gather(monkeypatch):
+    """The TPU route on the CPU (Pallas interpreter): a flood to 99%
+    through auto's hybrid choice gives the gather's rounds, messages and
+    seen set, from several sources."""
+    import functools
+
+    g = G.watts_strogatz(3000, 10, 0.1, seed=5, hybrid=True)
+    monkeypatch.setattr(segment, "_auto_or_method", functools.partial(
+        segment._auto_or_method, backend="tpu"))
+    key = jax.random.key(0)
+    for source in (0, 1499, 2999):
+        before = _route_count("hybrid")
+        st_h, sum_h = engine.run_until_coverage(g, Flood(source=source), key)
+        assert _route_count("hybrid") > before  # the route was traced
+        st_g, sum_g = engine.run_until_coverage(
+            g, Flood(source=source, method="gather"), key)
+        assert sum_h["rounds"] == sum_g["rounds"] > 1
+        assert sum_h["messages"] == sum_g["messages"]
+        assert sum_h["coverage"] == sum_g["coverage"] >= 0.99
+        assert (np.asarray(st_h.seen) == np.asarray(st_g.seen)).all()
