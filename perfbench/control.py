@@ -110,9 +110,7 @@ def main(argv=None) -> int:
     ctx = run.Context(spec, cell, config, traffic, seeds[0], args.seconds,
                       False)
     ctx.device = device
-    import graphs
-
-    ctx.graph, ctx.overlay, _ = graphs.build(config)
+    run.build_graph(ctx)
     kind = traffic["driver"]
     driver = harness.load_module("drivers", kind).Driver(ctx)
     try:

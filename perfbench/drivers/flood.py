@@ -30,6 +30,12 @@ def flood_seconds(window_start: float, ends) -> float:
     return (ends[-1] - window_start) / len(ends)
 
 
+def seen_of(state):
+    """A flood's seen set: the engine's ``FloodState.seen``, or the ring's
+    ``(seen, frontier)`` pair, ``[shards, block]`` in node order."""
+    return state[0] if isinstance(state, tuple) else state.seen
+
+
 class Driver:
     def __init__(self, ctx):
         self.ctx = ctx
@@ -42,7 +48,9 @@ class Driver:
     def flood(self, source: int) -> dict:
         from p2pnetwork_tpu.models.flood import Flood
 
-        self.node.attach_simulation(self.ctx.graph, Flood(source=int(source)))
+        ring = {} if self.ctx.mesh is None else {"mesh": self.ctx.mesh}
+        self.node.attach_simulation(self.ctx.graph, Flood(source=int(source)),
+                                    **ring)
         return self.node.run_until_coverage(self.target,
                                             max_rounds=self.max_rounds)
 
@@ -62,10 +70,12 @@ class Driver:
 
     def window(self, seed: int, seconds: float) -> dict:
         """Floods from the seed's sources after the warm-up's until
-        ``seconds`` have passed; returns their records and, for the sampled ones, the seen sets."""
+        ``seconds`` have passed; returns their records and, for the
+        sampled ones, the seen sets (each flood's is kept on the device
+        until the window has closed)."""
         mix, tracer = self.ctx.traffic, self.ctx.tracer
         pool = self.sources(seed)[int(mix["warmup_floods"]):]
-        floods, states = [], []
+        floods, seen = [], []
         tracer.start()
         self.ctx.window_open()
         t0 = time.perf_counter()
@@ -79,7 +89,7 @@ class Driver:
                 floods.append({"source": int(s), "start": start, "end": end,
                                "rounds": int(summary["rounds"]),
                                "messages": int(summary["messages"])})
-                states.append(self.node.sim_state)
+                seen.append(seen_of(self.node.sim_state))
                 if end >= deadline:
                     break
         tracer.stop()
@@ -89,8 +99,7 @@ class Driver:
         self.ctx.window_closed()
         picked = check.sample(traffic.rng_for(seed, 3), len(floods),
                               int(mix["check_sample"]))
-        items = [{**floods[i], "seen": np.asarray(states[i].seen)}
-                 for i in picked]
+        items = [{**floods[i], "seen": np.asarray(seen[i])} for i in picked]
         return {"t0": t0, "floods": floods, "items": items,
                 "window_s": floods[-1]["end"] - t0}
 

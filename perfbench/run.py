@@ -20,6 +20,7 @@ import argparse
 import gc
 import json
 import os
+import resource
 import sys
 import time
 
@@ -30,6 +31,35 @@ import harness  # noqa: E402
 T_PROCESS = harness.process_start()
 #: Persistent-cache lookups seen so far, by outcome (``count_compiles``).
 COMPILES: dict = {}
+
+
+def program_counters() -> dict:
+    """The program's counters and histograms (``/metrics``,
+    ``telemetry.default_registry()``), flat: ``name{label=value,...}`` to
+    its value, a histogram as its ``_sum`` and ``_count``."""
+    from p2pnetwork_tpu import telemetry
+
+    out = {}
+    for name, family in telemetry.default_registry().snapshot().items():
+        if family["type"] not in ("counter", "histogram"):
+            continue
+        for sample in family["samples"]:
+            labels = ",".join(f"{k}={v}" for k, v in
+                              sorted(sample["labels"].items()))
+            labels = f"{{{labels}}}" if labels else ""
+            if "count" in sample:
+                out[f"{name}_sum{labels}"] = sample["sum"]
+                out[f"{name}_count{labels}"] = sample["count"]
+            else:
+                out[f"{name}{labels}"] = sample["value"]
+    return out
+
+
+def moved(before: dict, after: dict) -> dict:
+    """The counters that moved between two :func:`program_counters`
+    readings, by how much."""
+    return {k: v - before.get(k, 0) for k, v in after.items()
+            if v != before.get(k, 0)}
 
 
 def count_compiles() -> None:
@@ -44,7 +74,8 @@ def count_compiles() -> None:
 
 class Context:
     """What a driver gets: the cell, its files, the program's graph, the
-    benchmark's overlay, the tracer, and the seams it reports through."""
+    benchmark's overlay, the ring the graph is placed on (``mesh``, ``None``
+    on one chip), the tracer, and the seams it reports through."""
 
     def __init__(self, spec, cell, config, traffic, seed, seconds, trace):
         import tracing
@@ -54,16 +85,20 @@ class Context:
         self.seed, self.seconds = int(seed), float(seconds)
         self.tracer = tracing.Tracer(trace, os.path.join(
             harness.TRACE_DIR, cell["name"]))
-        self.graph = self.overlay = None
+        self.graph = self.overlay = self.mesh = None
         self.setup_s = None
         self.memory_peak_bytes = None
         self.graph_info = {}
         self.compiles_at_open = {}
         self.window_compiles = {}
+        self.counters_at_open = {}
+        self.window_counters = {}
+        self.released_at = None
         self.layout_cache = True
 
     def window_open(self) -> None:
         self.compiles_at_open = dict(COMPILES)
+        self.counters_at_open = program_counters()
 
     def compiles_in_window(self) -> dict:
         """Cache hits and misses (misses compile) between the window's open
@@ -81,11 +116,14 @@ class Context:
     def window_closed(self) -> None:
         self.memory_peak_bytes = harness.memory_peak_bytes(self.cell["chips"])
         self.window_compiles = self.compiles_in_window()
+        self.window_counters = moved(self.counters_at_open,
+                                     program_counters())
 
     def release_program(self) -> None:
         """Drop the program's graph and state before the reference runs."""
         self.graph = None
         gc.collect()
+        self.released_at = time.perf_counter()
 
 
 def per_layer(ctx, result: dict, trace_numbers) -> dict:
@@ -100,16 +138,27 @@ def per_layer(ctx, result: dict, trace_numbers) -> dict:
     return out
 
 
+def build_graph(ctx) -> None:
+    """The ring the configuration places its overlay on (refused before
+    any graph is built where it does not fit the cell's chips), then the
+    program's graph and the benchmark's overlay."""
+    import graphs
+
+    ctx.mesh = graphs.mesh(ctx.config, int(ctx.cell["chips"]))
+    ctx.graph, ctx.overlay, ctx.graph_info = graphs.build(
+        ctx.config, on_host=ctx.mesh is not None, cache=ctx.layout_cache)
+    ctx.mark(f"graph {json.dumps(ctx.graph_info)}")
+
+
 def run_cell(ctx) -> dict:
     """Drive the cell; returns the result line's fields."""
     import check
-    import graphs
 
-    ctx.graph, ctx.overlay, ctx.graph_info = graphs.build(
-        ctx.config, cache=ctx.layout_cache)
-    ctx.mark(f"graph {json.dumps(ctx.graph_info)}")
+    build_graph(ctx)
     driver = harness.load_module("drivers", ctx.traffic["driver"])
     result = driver.run(ctx)
+    result["stats"]["check_s"] = time.perf_counter() - ctx.released_at
+    result["stats"]["counters"] = ctx.window_counters
     correct, shown = check.verdict(result["numbers"])
 
     device = dict(ctx.device)
@@ -137,6 +186,8 @@ def run_cell(ctx) -> dict:
     stats = {k: v for k, v in result["stats"].items()
              if k not in ("floods", "phases0", "phases1")}
     stats["window_compiles"] = ctx.window_compiles
+    stats["host_peak_rss_bytes"] = \
+        resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
     harness.log(f"stats {json.dumps(stats)}")
     line["checks"] = shown
     return line

@@ -25,7 +25,6 @@ import harness  # noqa: E402
 
 def main(argv=None) -> int:
     import check
-    import graphs
     import run
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
@@ -45,7 +44,7 @@ def main(argv=None) -> int:
     ctx = run.Context(spec, cell, config, traffic, args.seed, args.seconds,
                       False)
     ctx.device = device
-    ctx.graph, ctx.overlay, _ = graphs.build(config)
+    run.build_graph(ctx)
     driver = harness.load_module("drivers", traffic["driver"]).Driver(ctx)
     try:
         driver.setup(args.seed)

@@ -71,9 +71,7 @@ def test_sound_run_is_correct(workload):
 @pytest.mark.parametrize("workload", CELLS)
 def test_control_is_not_correct(workload):
     ctx = make_ctx(workload)
-    import graphs
-
-    ctx.graph, ctx.overlay, _ = graphs.build(ctx.config, cache=False)
+    run.build_graph(ctx)
     kind = ctx.traffic["driver"]
     driver = harness.load_module("drivers", kind).Driver(ctx)
     try:

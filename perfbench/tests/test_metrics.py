@@ -1,11 +1,13 @@
 """Metric arithmetic: the window, the tail, the readers, the reduction."""
 
+import json
 import math
 import os
 
 import pytest
 
 import harness
+import run
 import tracing
 from drivers import flood, serve
 
@@ -107,16 +109,71 @@ def test_reduce_refuses_a_trace_without_window_or_ops():
         tracing.reduce({"/device:TPU:0": []}, host)
 
 
-@pytest.mark.parametrize("name,stats,trace,want", [
+READER_CASES = [
     ("flood_host_ms", {}, {"spans": {"flood": [(2.0, 1.5), (1.0, 0.5)]}}, 500.0),
     ("round_device_ms.flood", {"rounds": 20}, {"busy_s": 3.0}, 150.0),
     ("round_device_ms.serve", {"rounds": 40}, {"busy_s": 2.0}, 50.0),
     ("device_idle.flood", {}, {"busy_s": 7.5, "window_s": 10.0}, 25.0),
     ("device_idle.serve", {}, {"busy_s": 9.0, "window_s": 10.0}, 10.0),
-])
+]
+
+
+@pytest.mark.parametrize("name,stats,trace,want", READER_CASES)
 def test_readers(name, stats, trace, want):
     assert harness.load_module("metrics", name).read(stats, trace) == \
         pytest.approx(want)
+
+
+@pytest.mark.parametrize("name,stats,trace,want", READER_CASES)
+def test_readers_read_the_same_beside_the_window_counters(name, stats, trace,
+                                                          want):
+    stats = dict(stats, counters={
+        "sim_dispatch_seconds_total{loop=coverage}": 9.0,
+        "sim_attach_seconds_total": 0.5})
+    assert harness.load_module("metrics", name).read(stats, trace) == \
+        pytest.approx(want)
+
+
+def test_moved_keeps_the_counters_that_moved_by_how_much():
+    before = {"a_total": 1.0, "b_total{loop=batch}": 2.0}
+    after = {"a_total": 1.0, "b_total{loop=batch}": 5.0, "c_seconds_count": 3}
+    assert run.moved(before, after) == {"b_total{loop=batch}": 3.0,
+                                        "c_seconds_count": 3}
+
+
+def test_program_counters_flatten_the_registry():
+    from p2pnetwork_tpu import telemetry
+
+    reg = telemetry.Registry()
+    prev = telemetry.set_default_registry(reg)
+    try:
+        reg.counter("x_total", "", ("loop", "op")).labels(
+            loop="batch", op="or").inc(2)
+        reg.histogram("w_seconds", "").observe(0.5)
+        reg.gauge("depth", "").set(3)
+        assert run.program_counters() == {
+            "x_total{loop=batch,op=or}": 2.0, "w_seconds_sum": 0.5,
+            "w_seconds_count": 1}
+    finally:
+        telemetry.set_default_registry(prev)
+
+
+def test_run_cell_prints_the_window_counters(monkeypatch, capsys):
+    import strata
+    from test_correctness import make_ctx
+
+    real = strata.compute
+    monkeypatch.setattr(strata, "load",
+                        lambda config, mix: real(config, mix, 64)["by_rounds"])
+    line = run.run_cell(make_ctx("ba-1m.flood"))
+    stats = [json.loads(ln.split(" ", 2)[2]) for ln in
+             capsys.readouterr().err.splitlines()
+             if ln.startswith("# stats ")]
+    counters = stats[-1]["counters"]
+    # Each flood of the window attached and dispatched once.
+    assert counters["sim_dispatch_seconds_total{loop=coverage_from}"] > 0
+    assert counters["sim_attach_seconds_total"] > 0
+    assert line["correct"]
 
 
 def test_tick_host_ms_differences_the_totals():
