@@ -90,6 +90,29 @@ def build_hybrid(
     )
 
 
+def kept_offsets(off: np.ndarray, n: int, max_diags: int = 64,
+                 min_count: Optional[int] = None) -> list:
+    """The circular offsets ``off`` (one per edge, in ``[0, n)``; 0 for
+    edges that are no candidates) keeps as diagonals: those carrying at
+    least ``min_count`` edges (default ``max(n // 256, 128)``), by
+    descending edge count, at most ``max_diags``. Shared by
+    :func:`select_diagonals` and the ring layout (parallel/ringlayout.py),
+    so both keep the same offsets in the same order."""
+    if min_count is None:
+        min_count = max(n // 256, 128)
+    if not off.size:
+        return []
+    counts = np.bincount(off)
+    # Filter (self-loops, below-threshold) BEFORE truncating to
+    # max_diags — a frequent self-loop offset ranking in the top
+    # max_diags must not displace a qualifying real diagonal into the
+    # per-edge remainder. Vectorized: `counts` has up to n entries.
+    ok = counts >= min_count
+    ok[0] = False
+    cand = np.flatnonzero(ok)
+    return [int(o) for o in cand[np.argsort(counts[cand])[::-1]][:max_diags]]
+
+
 def select_diagonals(
     senders: np.ndarray,
     receivers: np.ndarray,
@@ -103,8 +126,9 @@ def select_diagonals(
     offsets (by descending edge count), for each one the indices of its
     covered edges — deduplicated to ONE edge per receiver, so sums count
     every edge instance exactly once — and the overall covered bitmap.
-    Shared by the single-chip hybrid build and the sharded ring's
-    decomposition (parallel/sharded.py), so selection tuning cannot
+    The single-chip hybrid build uses it; the sharded ring's layout
+    (parallel/ringlayout.py) shares its :func:`kept_offsets` and its rule
+    of one edge per (offset, receiver), so selection tuning cannot
     silently diverge the two paths. Edges touching padded ids (``>= n``,
     possible when folded-in dynamic links involve spare nodes) are never
     candidates — their offsets-mod-n would alias real diagonals.
@@ -124,15 +148,7 @@ def select_diagonals(
         # graph build at BASELINE scale.
         d = senders[real].astype(np.int32) - receivers[real].astype(np.int32)
         off = np.where(d < 0, d + np.int32(n), d)
-        counts = np.bincount(off)
-        # Filter (self-loops, below-threshold) BEFORE truncating to
-        # max_diags — a frequent self-loop offset ranking in the top
-        # max_diags must not displace a qualifying real diagonal into the
-        # per-edge remainder. Vectorized: `counts` has up to n entries.
-        ok = counts >= min_count
-        ok[0] = False
-        cand = np.flatnonzero(ok)
-        kept = [int(o) for o in cand[np.argsort(counts[cand])[::-1]][:max_diags]]
+        kept = kept_offsets(off, n, max_diags, min_count)
         # One sort pass gives every diagonal's edge set as a contiguous
         # slice (instead of a full O(E) scan per kept offset), through the
         # native radix kernel: on low-cardinality offset distributions

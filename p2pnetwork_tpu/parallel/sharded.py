@@ -51,6 +51,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from p2pnetwork_tpu import telemetry
 from p2pnetwork_tpu.parallel.mesh import DEFAULT_AXIS
 from p2pnetwork_tpu.sim import flightrec
 from p2pnetwork_tpu.sim.graph import Graph, _round_up
@@ -323,202 +324,157 @@ def _mxu_or_empty(sg: ShardedGraph):
     )
 
 
-def _extract_ring_diagonals(senders, receivers, n, S, block, max_diags,
-                            min_count):
-    """Select dominant circular diagonals and decompose each into static
-    ring pieces (host-side; see ShardedGraph.diag_pieces).
+class ShardKeep:
+    """A caller's hold on the pristine result of :func:`shard_graph`.
+    Passed back as ``keep=``, the kept ``ShardedGraph`` is returned as is
+    for the same graph object, mesh and flags: no host work, and no second
+    copy on the chips. Any other call replaces it. Churn on the mesh makes
+    new ``ShardedGraph``s and leaves the kept one as it was built.
+    ``JaxSimNode`` keeps one across attaches."""
 
-    Returns ``(pieces, masks [S, P, block], diag_sel)`` where ``diag_sel``
-    flags the edges covered (the rest go to the bucket remainder). Edges
-    whose signed offset wraps the real-node boundary (``v + off_s`` outside
-    ``[0, n)``) stay in the remainder — only the uniform no-wrap body of a
-    diagonal has the shard-invariant piece structure.
-    """
-    from p2pnetwork_tpu.ops.diag import select_diagonals
+    __slots__ = ("graph", "key", "sg")
 
-    kept, per_sel, diag_sel = select_diagonals(
-        senders, receivers, n, max_diags, min_count
-    )
-    pieces = []
-    mask_rows = []
-    for o, sel in zip(kept, per_sel):
-        off_s = o if o <= n // 2 else o - n
-        v = receivers[sel].astype(np.int64)
-        nowrap = (v + off_s >= 0) & (v + off_s < n)
-        dropped = sel[~nowrap]
-        diag_sel[dropped] = False  # wrap edges ride the remainder
-        sel = sel[nowrap]
-        if not sel.size:
-            continue
-        dmask = np.zeros(S * block, dtype=bool)
-        dmask[receivers[sel]] = True
-        dmask = dmask.reshape(S, block)
-        q, r = divmod(off_s, block)  # floor division: r in [0, block)
-        j = np.arange(block)
-        piece_a = dmask & (j + r < block)[None, :]
-        piece_b = dmask & (j + r >= block)[None, :]
-        t_a = (-q) % S
-        t_b = (-q - 1) % S
-        if S == 1 or t_a == t_b:
-            if piece_a.any() or piece_b.any():
-                pieces.append((t_a, int(r)))  # graftlint: ignore[host-sync-in-loop] -- r is a host int from divmod
-                mask_rows.append(dmask)
-        else:
-            if piece_a.any():
-                pieces.append((t_a, int(r)))  # graftlint: ignore[host-sync-in-loop] -- host int
-                mask_rows.append(piece_a)
-            if piece_b.any():
-                pieces.append((t_b, int(r)))  # graftlint: ignore[host-sync-in-loop] -- host int
-                mask_rows.append(piece_b)
-    if not pieces:
-        return (), None, diag_sel
-    masks = np.stack(mask_rows, axis=1)  # [S, P, block]
-    return tuple(pieces), masks, diag_sel
+    def __init__(self):
+        self.clear()
+
+    def clear(self) -> None:
+        self.graph = self.key = self.sg = None
 
 
-def shard_graph(graph: Graph, mesh: Mesh, axis_name: str = DEFAULT_AXIS,
-                edge_pad_multiple: int = 128, mxu: bool = False,
-                hybrid: bool = False, max_diags: int = 64,
-                min_count: Optional[int] = None,
-                source_csr: bool = False) -> ShardedGraph:
-    """Partition ``graph`` for ``mesh`` (host-side; one-off setup).
+def _count_shard(result: str, t0: float) -> None:
+    reg = telemetry.default_registry()
+    reg.counter(
+        "sim_shard_total",
+        "shard_graph calls by result: built (the ring layout built from "
+        "the graph's edges), from_layout (the graph's own ring layout "
+        "padded and placed), reused (a kept ShardedGraph returned).",
+        ("result",)).labels(result).inc()
+    reg.counter(
+        "sim_shard_seconds_total",
+        "Host seconds in shard_graph: the ring layout built or taken from "
+        "the graph, padded and placed on the mesh.",
+    ).inc(time.perf_counter() - t0)
 
-    Nodes are split into ``S`` contiguous blocks. Every active edge lands in
-    bucket ``(dst_shard, ring_step)`` where ``ring_step = (dst_shard -
-    src_shard) mod S`` — the step of the ring rotation at which the sender's
-    frontier block is resident on the receiver's shard.
 
-    A graph carrying live dynamic edges (sim/topology.py) is sharded
-    losslessly: its runtime links are folded into the static buckets (this
-    IS the documented consolidation path — re-shard when churn accumulates).
+def _fitting_layout(graph: Graph, S: int, hybrid: bool, max_diags: int,
+                    min_count: Optional[int]):
+    """The graph's own ring layout where it was built for ``S`` shards
+    (and, for ``hybrid``, with this diagonal selection) from exactly the
+    graph's live edges: every static edge live, no dynamic link live.
+    Else ``None``."""
+    from p2pnetwork_tpu.parallel.ringlayout import ring_block
 
-    ``mxu=True`` additionally builds the per-bucket one-hot-matmul layout
-    (see ``ShardedGraph.mxu_src``) — on TPU the ring pass then runs on the
-    MXU instead of XLA's scatter lowering of segment reductions (~2x per
-    chip at 1M nodes; measured in benchmarks/ladder.py).
+    lay = graph.ring
+    if (lay is None or lay.n_shards != S or lay.n_edges != graph.n_edges
+            or lay.block != ring_block(graph.n_nodes_padded, S)):
+        return None
+    if hybrid and not (lay.hybrid and lay.max_diags == max_diags
+                       and lay.min_count == min_count):
+        return None
+    if graph.dyn_mask is not None and np.asarray(graph.dyn_mask).any():
+        return None
+    emask = np.asarray(graph.edge_mask)
+    if not emask[:graph.n_edges].all() or emask[graph.n_edges:].any():
+        return None
+    return lay
 
-    ``source_csr=True`` additionally builds the per-shard sender-CSR view
-    (``csr_pos``/``csr_offsets``) that the frontier-adaptive coverage loop
-    gathers small frontiers through (see :func:`flood_until_coverage`'s
-    ``adaptive_k``).
-    """
-    S = mesh.shape[axis_name]
+
+def _build_layout(graph: Graph, S: int, hybrid: bool, max_diags: int,
+                  min_count: Optional[int]):
+    """The ring layout of the graph's live edges, its live dynamic links
+    folded in (receiver-sorted first, as ``ringlayout.build`` asks)."""
+    from p2pnetwork_tpu import native
+    from p2pnetwork_tpu.parallel import ringlayout
+
     emask = np.asarray(graph.edge_mask)
     senders = np.asarray(graph.senders)[emask]
     receivers = np.asarray(graph.receivers)[emask]
     if graph.dyn_mask is not None:
         dmask = np.asarray(graph.dyn_mask)
-        senders = np.concatenate([senders, np.asarray(graph.dyn_senders)[dmask]])
-        receivers = np.concatenate([receivers, np.asarray(graph.dyn_receivers)[dmask]])
+        if dmask.any():
+            senders = np.concatenate(
+                [senders, np.asarray(graph.dyn_senders)[dmask]])
+            receivers = np.concatenate(
+                [receivers, np.asarray(graph.dyn_receivers)[dmask]])
+            receivers, senders = native.sort_pairs(receivers, senders)
+    return ringlayout.build(senders, receivers, graph.n_nodes,
+                            graph.n_nodes_padded, S, hybrid=hybrid,
+                            max_diags=max_diags, min_count=min_count)
 
-    block = _round_up(graph.n_nodes_padded, S) // S
 
-    # Diagonal extraction must precede bucketing (the selection indexes the
-    # unsorted edge arrays); the covered edges leave the APPLIED remainder
-    # but stay in the bkt_* truth arrays below (degrees, probe, remask).
+def _source_csr(layout, e_bkt: int, edge_pad_multiple: int):
+    """The per-shard sender-CSR view (``ShardedGraph.csr_pos`` /
+    ``csr_offsets`` / ``csr_span``) of a ring layout whose buckets are
+    padded to ``e_bkt`` slots."""
+    from p2pnetwork_tpu import native
+
+    S, block = layout.n_shards, layout.block
+    n_g = S * block
+    rows_pos = []
+    counts = np.zeros((S, n_g), dtype=np.int64)
+    for d in range(S):
+        # This shard's live bucket slots, flattened (t * e_bkt + slot),
+        # keyed by the GLOBAL sender id: step t holds senders of shard
+        # (d - t) mod S.
+        g_send, pos = [], []
+        for t in range(S):
+            local = layout.src[layout.bucket(d, t)]
+            g_send.append(((d - t) % S) * block + local.astype(np.int64))
+            pos.append(t * e_bkt + np.arange(local.size, dtype=np.int64))
+        g_send = np.concatenate(g_send).astype(np.int32)
+        _, pos_sorted = native.sort_pairs(
+            g_send, np.concatenate(pos).astype(np.int32))
+        rows_pos.append(pos_sorted)
+        counts[d] = np.bincount(g_send, minlength=n_g)
+    e_s = _round_up(max(max(p.size for p in rows_pos), 1), edge_pad_multiple)
+    csr_pos = np.zeros((S, e_s), dtype=np.int32)
+    for d in range(S):
+        csr_pos[d, : rows_pos[d].size] = rows_pos[d]
+    csr_offsets = np.zeros((S, n_g + 1), dtype=np.int32)
+    np.cumsum(counts, axis=1, out=csr_offsets[:, 1:])
+    return csr_pos, csr_offsets, int(counts.max()) if counts.size else 0
+
+
+def _place(layout, graph: Graph, mesh: Mesh, axis_name: str,
+           edge_pad_multiple: int, mxu: bool, hybrid: bool,
+           source_csr: bool) -> ShardedGraph:
+    """Pad a ring layout and the graph's node arrays and place them on
+    ``mesh``. The ``[S, S, E_bkt]`` buckets are padded one shard at a
+    time, as the mesh takes them, so the host never holds them whole."""
+    from p2pnetwork_tpu.parallel.ringlayout import MXU_BLOCK
+
+    S, block = layout.n_shards, layout.block
+    e_bkt = _round_up(max(int(layout.counts.max()), 1), edge_pad_multiple)
+    shard = NamedSharding(mesh, P(axis_name))
+    dev = lambda x: jax.device_put(x, shard)  # noqa: E731
+
+    def buckets(values, fill, dtype):
+        def piece(index):
+            rows = range(S)[index[0]]
+            out = np.full((len(rows), S, e_bkt), fill, dtype)
+            for i, d in enumerate(rows):
+                for t in range(S):
+                    sl = layout.bucket(d, t)
+                    out[i, t, : sl.stop - sl.start] = (
+                        True if values is None else values[sl])
+            return out
+        return jax.make_array_from_callback((S, S, e_bkt), shard, piece)
+
+    mxu_arrays = (None, None, None)
     diag_pieces: Tuple[Tuple[int, int], ...] = ()
     diag_masks = None
     if hybrid:
-        diag_pieces, diag_masks, diag_sel = _extract_ring_diagonals(
-            senders, receivers, graph.n_nodes, S, block, max_diags, min_count
-        )
-        mxu = True  # the remainder rides the MXU buckets
-    else:
-        diag_sel = np.zeros(senders.shape[0], dtype=bool)
-
-    def _bucketize(s_arr, r_arr):
-        """Sort edges by (bucket, local dst); return sorted arrays, bucket
-        offsets (bucket = dst_shard * S + ring_step), sorted bucket ids,
-        and the sort order."""
-        flat = (r_arr // block) * S + ((r_arr // block) - (s_arr // block)) % S
-        order = np.lexsort((r_arr, flat))
-        s_arr, r_arr, flat = s_arr[order], r_arr[order], flat[order]
-        offs = np.zeros(S * S + 1, dtype=np.int64)
-        np.cumsum(np.bincount(flat, minlength=S * S), out=offs[1:])
-        return s_arr, r_arr, offs, flat, order
-
-    senders_b, receivers_b, offsets, flat_b, order_b = _bucketize(
-        senders, receivers
-    )
-    e_bkt = _round_up(
-        max(int(np.diff(offsets).max()), 1), edge_pad_multiple
-    )
-    bkt_src = np.zeros((S, S, e_bkt), dtype=np.int32)
-    # Pad destinations with block-1 so each bucket stays dst-sorted — the
-    # segment reductions in the ring body promise indices_are_sorted=True.
-    bkt_dst = np.full((S, S, e_bkt), block - 1, dtype=np.int32)
-    bkt_mask = np.zeros((S, S, e_bkt), dtype=bool)
-    for d in range(S):
-        for t in range(S):
-            b = d * S + t
-            lo, hi = offsets[b], offsets[b + 1]
-            cnt = hi - lo
-            bkt_src[d, t, :cnt] = senders_b[lo:hi] % block
-            bkt_dst[d, t, :cnt] = receivers_b[lo:hi] % block
-            bkt_mask[d, t, :cnt] = True
-
-    mxu_src = mxu_dst = mxu_mask = None
-    mxu_block = 512  # ops/diag.py's remainder block: less padding waste
-    if mxu:
-        from p2pnetwork_tpu.ops.blocked import build_blocked_arrays_np
-
-        # A subset of the already-bucket-sorted arrays stays sorted — no
-        # second O(E log E) lexsort for the remainder.
-        ks = ~diag_sel[order_b]
-        rem_s, rem_r = senders_b[ks], receivers_b[ks]
-        rem_offs = np.zeros(S * S + 1, dtype=np.int64)
-        np.cumsum(np.bincount(flat_b[ks], minlength=S * S), out=rem_offs[1:])
-        per_bucket = []
-        for d in range(S):
-            for t in range(S):
-                b = d * S + t
-                lo_, hi_ = rem_offs[b], rem_offs[b + 1]
-                per_bucket.append(build_blocked_arrays_np(
-                    (rem_s[lo_:hi_] % block).astype(np.int32),
-                    (rem_r[lo_:hi_] % block).astype(np.int32),
-                    block, mxu_block,
-                ))
-        nb = max(bs.shape[0] for bs, _, _ in per_bucket)
-        w = max(bs.shape[1] for bs, _, _ in per_bucket)
-        mxu_src = np.zeros((S, S, nb, w), np.int32)
-        mxu_dst = np.zeros((S, S, nb, w), np.int32)
-        mxu_mask = np.zeros((S, S, nb, w), bool)
-        for d in range(S):
-            for t in range(S):
-                bs, bd, bm = per_bucket[d * S + t]
-                r, c = bs.shape
-                mxu_src[d, t, :r, :c] = bs
-                mxu_dst[d, t, :r, :c] = bd
-                mxu_mask[d, t, :r, :c] = bm
-
+        # The diagonals' edges leave the APPLIED remainder but stay in
+        # the bkt_* truth arrays (degrees, probe, remask).
+        diag_pieces, diag_masks = layout.diag_pieces, layout.diag_masks
+        mxu_arrays = (layout.mxu_src, layout.mxu_dst, layout.mxu_mask)
+    elif mxu:
+        mxu_arrays = layout.mxu_blocks()
     csr_pos = csr_offsets = None
     csr_span = 0
     if source_csr:
-        from p2pnetwork_tpu import native
-
-        n_g = S * block
-        rows_pos = []
-        counts = np.zeros((S, n_g), dtype=np.int64)
-        for d in range(S):
-            # This shard's live bucket slots, flattened (t * e_bkt + slot),
-            # keyed by the GLOBAL sender id reconstructed from the ring
-            # step: step t holds senders of shard (d - t) mod S.
-            t_idx, slot_idx = np.nonzero(bkt_mask[d])
-            g_send = (
-                ((d - t_idx) % S) * block + bkt_src[d, t_idx, slot_idx]
-            ).astype(np.int32)
-            pos = (t_idx * e_bkt + slot_idx).astype(np.int32)
-            _, pos_sorted = native.sort_pairs(g_send, pos)
-            rows_pos.append(pos_sorted)
-            counts[d] = np.bincount(g_send, minlength=n_g)
-        e_s = _round_up(max(max(p.size for p in rows_pos), 1),
-                        edge_pad_multiple)
-        csr_pos = np.zeros((S, e_s), dtype=np.int32)
-        for d in range(S):
-            csr_pos[d, : rows_pos[d].size] = rows_pos[d]
-        csr_offsets = np.zeros((S, n_g + 1), dtype=np.int32)
-        np.cumsum(counts, axis=1, out=csr_offsets[:, 1:])
-        csr_span = int(counts.max()) if counts.size else 0
+        csr_pos, csr_offsets, csr_span = _source_csr(layout, e_bkt,
+                                                     edge_pad_multiple)
 
     pad_n = S * block - graph.n_nodes_padded
     node_mask = np.pad(np.asarray(graph.node_mask), (0, pad_n))
@@ -530,13 +486,14 @@ def shard_graph(graph: Graph, mesh: Mesh, axis_name: str = DEFAULT_AXIS,
         neighbors_mask = np.pad(
             np.asarray(graph.neighbor_mask), ((0, pad_n), (0, 0))
         )
-
-    shard = NamedSharding(mesh, P(axis_name))
-    dev = lambda x: jax.device_put(x, shard)  # noqa: E731
+    opt = lambda x: None if x is None else dev(x)  # noqa: E731
     return ShardedGraph(
-        bkt_src=dev(bkt_src),
-        bkt_dst=dev(bkt_dst),
-        bkt_mask=dev(bkt_mask),
+        bkt_src=buckets(layout.src, 0, np.int32),
+        # Pad destinations with block-1 so each bucket stays dst-sorted —
+        # the segment reductions in the ring body promise
+        # indices_are_sorted=True.
+        bkt_dst=buckets(layout.dst, block - 1, np.int32),
+        bkt_mask=buckets(None, False, bool),
         node_mask=dev(node_mask.reshape(S, block)),
         out_degree=dev(out_degree.reshape(S, block).astype(np.int32)),
         in_degree=dev(in_degree.reshape(S, block).astype(np.int32)),
@@ -549,16 +506,80 @@ def shard_graph(graph: Graph, mesh: Mesh, axis_name: str = DEFAULT_AXIS,
         neighbors_mask=None if neighbors_mask is None else dev(
             neighbors_mask.reshape(S, block, -1)
         ),
-        mxu_src=None if mxu_src is None else dev(mxu_src),
-        mxu_dst=None if mxu_dst is None else dev(mxu_dst),
-        mxu_mask=None if mxu_mask is None else dev(mxu_mask),
-        diag_masks=None if diag_masks is None else dev(diag_masks),
+        mxu_src=opt(mxu_arrays[0]),
+        mxu_dst=opt(mxu_arrays[1]),
+        mxu_mask=opt(mxu_arrays[2]),
+        diag_masks=opt(diag_masks),
         diag_pieces=diag_pieces,
-        mxu_block=mxu_block,
-        csr_pos=None if csr_pos is None else dev(csr_pos),
-        csr_offsets=None if csr_offsets is None else dev(csr_offsets),
+        mxu_block=MXU_BLOCK,
+        csr_pos=opt(csr_pos),
+        csr_offsets=opt(csr_offsets),
         csr_span=csr_span,
     )
+
+
+def shard_graph(graph: Graph, mesh: Mesh, axis_name: str = DEFAULT_AXIS,
+                edge_pad_multiple: int = 128, mxu: bool = False,
+                hybrid: bool = False, max_diags: int = 64,
+                min_count: Optional[int] = None,
+                source_csr: bool = False,
+                keep: Optional[ShardKeep] = None) -> ShardedGraph:
+    """Partition ``graph`` for ``mesh`` (host-side; one-off setup).
+
+    Nodes are split into ``S`` contiguous blocks. Every active edge lands in
+    bucket ``(dst_shard, ring_step)`` where ``ring_step = (dst_shard -
+    src_shard) mod S`` — the step of the ring rotation at which the sender's
+    frontier block is resident on the receiver's shard.
+
+    The host work is the ring layout's (parallel/ringlayout.py). A graph
+    built with ``from_edges(ring_shards=S)`` carries it: when it fits —
+    this ``S``, these diagonal settings, and exactly the edges it was built
+    from live — it is only padded and placed. Otherwise it is built here
+    from the graph's live edges, through the same functions, so the result
+    is the same array for array either way.
+
+    A graph carrying live dynamic edges (sim/topology.py) is sharded
+    losslessly: its runtime links are folded into the static buckets (this
+    IS the documented consolidation path — re-shard when churn accumulates).
+
+    ``mxu=True`` additionally builds the per-bucket one-hot-matmul layout
+    (see ``ShardedGraph.mxu_src``) — on TPU the ring pass then runs on the
+    MXU instead of XLA's scatter lowering of segment reductions (~2x per
+    chip at 1M nodes; measured in benchmarks/ladder.py). ``hybrid=True``
+    takes the ring-decomposed diagonals out of it (``diag_pieces``) and
+    builds the MXU layout of the remainder.
+
+    ``source_csr=True`` additionally builds the per-shard sender-CSR view
+    (``csr_pos``/``csr_offsets``) that the frontier-adaptive coverage loop
+    gathers small frontiers through (see :func:`flood_until_coverage`'s
+    ``adaptive_k``).
+
+    ``keep`` (a :class:`ShardKeep`) returns the kept result of an earlier
+    call from the same graph object with the same mesh and arguments, and
+    keeps this call's result otherwise. Calls are counted in
+    ``sim_shard_total{result=built|from_layout|reused}`` and their host
+    seconds in ``sim_shard_seconds_total``.
+    """
+    t0 = time.perf_counter()
+    key = (mesh, axis_name, edge_pad_multiple, mxu, hybrid, max_diags,
+           min_count, source_csr)
+    if keep is not None:
+        if keep.graph is graph and keep.key == key:
+            _count_shard("reused", t0)
+            return keep.sg
+        keep.clear()  # the old shard leaves the chips before the new comes
+    S = mesh.shape[axis_name]
+    layout = _fitting_layout(graph, S, hybrid, max_diags, min_count)
+    result = "from_layout"
+    if layout is None:
+        result = "built"
+        layout = _build_layout(graph, S, hybrid, max_diags, min_count)
+    sg = _place(layout, graph, mesh, axis_name, edge_pad_multiple,
+                mxu, hybrid, source_csr)
+    if keep is not None:
+        keep.graph, keep.key, keep.sg = graph, key, sg
+    _count_shard(result, t0)
+    return sg
 
 
 # --------------------------------------------------------------- churn ops
@@ -1691,7 +1712,65 @@ def flood_until_coverage(sg: ShardedGraph, mesh: Mesh, source: int, *,
     executed window hit are counted into
     ``chaos_device_faults_total{kind}`` after the run (dense loop only —
     the adaptive path refuses fault specs like it refuses the recorder).
+
+    Spans: ``run_dispatch`` (``loop="ring"``, as the engine's run-to-*
+    entries) from entry until the jitted loop call returns (initial state,
+    cache lookup, trace and lowering, launch) and ``ring_wait`` until the
+    packed summary is on the host, both on the
+    profiler's timeline too (telemetry/spans.py). Counters:
+    ``sim_ring_rounds_total`` and, on the dense loop,
+    ``sim_ring_halo_bytes_total`` (:func:`halo_bytes_per_round` a round).
     """
+    with spans.span("run_dispatch", loop="ring"):
+        (seen, frontier), packed, ring = _flood_cov_dispatch(
+            sg, mesh, source, coverage_target, max_rounds, axis_name, state0,
+            adaptive_k, comm, recorder, fault_round0)
+    with spans.span("ring_wait"):
+        if ring is not None:
+            packed, ring = jax.device_get((packed, ring))
+        out = accum.unpack_summary(packed)
+    _record_ring_rounds(sg, out["rounds"],
+                        None if adaptive_k > 0 else frontier)
+    _record_comm_faults(comm, out["rounds"], sg.n_shards, round0=fault_round0)
+    if ring is not None:
+        out["flight_record"] = flightrec.trim(ring, out["rounds"])
+    # The packed fifth slot is the mean per-round frontier occupancy —
+    # surface it under the engine's summary key (run-summary parity:
+    # engine.run_until_coverage on a flood returns the same dict).
+    occ = out.pop("extra", None)
+    if occ is not None:
+        out["frontier_occupancy_mean"] = occ
+    if return_state:
+        return (seen, frontier), out
+    return seen, out
+
+
+def halo_bytes_per_round(sg: ShardedGraph, payload) -> int:
+    """Bytes each chip sends in one ring pass of ``payload`` (one
+    ``[S, block]`` per-node array): ``S - 1`` hops of its block."""
+    return (sg.n_shards - 1) * sg.block * np.dtype(payload.dtype).itemsize
+
+
+def _record_ring_rounds(sg: ShardedGraph, rounds, frontier) -> None:
+    """Count a ring run's rounds, and (given the frontier it passed round
+    to round, the dense loop) the halo bytes each chip sent."""
+    rounds = int(rounds)
+    reg = telemetry.default_registry()
+    reg.counter("sim_ring_rounds_total",
+                "Rounds the ring's flood-to-coverage loop ran.").inc(rounds)
+    if frontier is not None:
+        reg.counter(
+            "sim_ring_halo_bytes_total",
+            "Bytes each chip sent to the next in the ring's dense flood "
+            "rounds: rounds x (S - 1) frontier blocks at the frontier's "
+            "dtype.").inc(rounds * halo_bytes_per_round(sg, frontier))
+
+
+def _flood_cov_dispatch(sg, mesh, source, coverage_target, max_rounds,
+                        axis_name, state0, adaptive_k, comm, recorder,
+                        fault_round0):
+    """:func:`flood_until_coverage` up to the jitted loop call's return:
+    ``((seen, frontier), packed summary, flight ring or None)``."""
     from p2pnetwork_tpu.models.flood import Flood
 
     S, block = sg.n_shards, sg.block
@@ -1761,20 +1840,7 @@ def flood_until_coverage(sg: ShardedGraph, mesh: Mesh, source: int, *,
                 jnp.float32(coverage_target), *common, seen0, frontier0,
                 recorder.init(), jnp.float32(ici), *ftail,
             )
-            packed, ring = jax.device_get((packed, ring))
-    out = accum.unpack_summary(packed)
-    _record_comm_faults(comm, out["rounds"], S, round0=fault_round0)
-    if ring is not None:
-        out["flight_record"] = flightrec.trim(ring, out["rounds"])
-    # The packed fifth slot is the mean per-round frontier occupancy —
-    # surface it under the engine's summary key (run-summary parity:
-    # engine.run_until_coverage on a flood returns the same dict).
-    occ = out.pop("extra", None)
-    if occ is not None:
-        out["frontier_occupancy_mean"] = occ
-    if return_state:
-        return (seen, frontier), out
-    return seen, out
+    return (seen, frontier), packed, ring
 
 
 # ------------------------------------------------------------------- gossip

@@ -467,10 +467,17 @@ _GRAPH_ARRAYS = (
 )
 
 
+#: The ring layout's arrays (parallel/ringlayout.RingLayout), saved as
+#: ``ring_<name>`` and loaded back as host arrays.
+_RING_ARRAYS = ("src", "dst", "offsets", "diag_masks", "mxu_src", "mxu_dst",
+                "mxu_mask")
+
+
 def save_graph(path: str, graph) -> None:
     """Atomically persist a built :class:`~p2pnetwork_tpu.sim.graph.Graph`
-    — including kernel layouts (blocked/hybrid/source-CSR), weights, the
-    dynamic region, and any liveness re-masking — as one ``.npz``.
+    — including kernel layouts (blocked/hybrid/source-CSR, the host-side
+    ring layout), weights, the dynamic region, and any liveness re-masking
+    — as one ``.npz``.
 
     The complement of the state checkpoints above: graph CONSTRUCTION is
     the host-side cost at scale (tens of seconds for the 100M-edge build,
@@ -524,6 +531,16 @@ def save_graph(path: str, graph) -> None:
             payload["hybrid_rem_local_dst"] = np.asarray(
                 jax.device_get(rem.local_dst))
             payload["hybrid_rem_mask"] = np.asarray(jax.device_get(rem.mask))
+    ring = graph.ring
+    if ring is not None:
+        meta["ring"] = {"n_shards": ring.n_shards, "block": ring.block,
+                        "n_edges": ring.n_edges, "hybrid": ring.hybrid,
+                        "max_diags": ring.max_diags,
+                        "min_count": ring.min_count,
+                        "diag_pieces": [list(p) for p in ring.diag_pieces]}
+        payload.update({f"ring_{name}": getattr(ring, name)
+                        for name in _RING_ARRAYS
+                        if getattr(ring, name) is not None})
     payload["__meta__"] = np.frombuffer(
         json.dumps(meta).encode(), dtype=np.uint8)
 
@@ -600,6 +617,19 @@ def load_graph(path: str):
                 n=int(meta["hybrid_n"]),
                 n_diag_edges=int(masks.sum()),
             )
+        ring = None
+        if "ring" in meta:
+            from p2pnetwork_tpu.parallel.ringlayout import RingLayout
+
+            rm = meta["ring"]
+            ring = RingLayout(
+                n_shards=int(rm["n_shards"]), block=int(rm["block"]),
+                n_edges=int(rm["n_edges"]), hybrid=bool(rm["hybrid"]),
+                max_diags=int(rm["max_diags"]), min_count=rm["min_count"],
+                diag_pieces=tuple(tuple(p) for p in rm["diag_pieces"]),
+                **{name: (data[f"ring_{name}"]
+                          if f"ring_{name}" in data.files else None)
+                   for name in _RING_ARRAYS})
         cap = meta.get("max_degree_cap")  # absent in pre-cap files
         return Graph(
             n_nodes=int(meta["n_nodes"]),
@@ -612,5 +642,6 @@ def load_graph(path: str):
             blocked=blocked,
             hybrid=hybrid,
             skew=skew,
+            ring=ring,
             **fields,
         )

@@ -234,6 +234,14 @@ class Graph:
     # ``layout.to_original_order``.
     layout_perm: Optional[jax.Array] = None  # i32[N_pad]
     layout_inv: Optional[jax.Array] = None  # i32[N_pad]
+    # Host-side ring layout for an S-shard mesh (parallel/ringlayout.py,
+    # ``from_edges(ring_shards=S)``): what parallel/sharded.shard_graph
+    # derives from the edges, built once with the graph and kept in the
+    # layout cache. Static (host arrays never ride a jitted call); edge
+    # changes (apply_delta, grow) drop it, and shard_graph uses it only
+    # while exactly the edges it was built from are live.
+    ring: Optional[object] = dataclasses.field(
+        default=None, metadata=dict(static=True))
 
     @property
     def n_nodes_padded(self) -> int:
@@ -947,6 +955,7 @@ def apply_delta(graph: Graph, delta: GraphDelta, *,
         hybrid=hybrid_rep,
         skew=skew_rep,
         max_out_span=max_out_span,
+        ring=None,
         **arrays,
     )
 
@@ -1051,7 +1060,7 @@ def _grow(graph: Graph, new_n: int, new_pad: int) -> Graph:
             hybrid_rep = build_hybrid_from_arrays(
                 s_live, r_live, new_n, n_pad, **kw)
         return dataclasses.replace(
-            graph, n_nodes=new_n, hybrid=hybrid_rep,
+            graph, n_nodes=new_n, hybrid=hybrid_rep, ring=None,
             node_mask=jax.device_put(nm))
 
     # Repad: rebuild exactly the capacity-dependent leaves. Everything
@@ -1133,7 +1142,7 @@ def _grow(graph: Graph, new_n: int, new_pad: int) -> Graph:
     arrays = jax.device_put(arrays)
     return dataclasses.replace(
         graph, n_nodes=new_n, blocked=blocked_rep, hybrid=hybrid_rep,
-        skew=skew_rep, **arrays)
+        skew=skew_rep, ring=None, **arrays)
 
 
 def from_edges(
@@ -1152,6 +1161,7 @@ def from_edges(
     source_csr: bool = False,
     weights=None,
     reorder: Optional[str] = None,
+    ring_shards: int = 0,
 ) -> Graph:
     """Build a :class:`Graph` from host-side edge arrays.
 
@@ -1175,6 +1185,11 @@ def from_edges(
     the graph (``layout_perm``/``layout_inv``) and every runtime id then
     speaks the relabeled space — map results back with
     ``layout.to_original_order``.
+
+    ``ring_shards=S`` (S > 0) attaches the host-side ring layout for an
+    ``S``-shard mesh (parallel/ringlayout.py: the bucketing, the diagonal
+    pieces and the MXU remainder of the default ``hybrid`` attach), which
+    ``parallel.sharded.shard_graph`` then only pads and places.
     """
     senders = np.asarray(senders, dtype=np.int32)
     receivers = np.asarray(receivers, dtype=np.int32)
@@ -1314,6 +1329,14 @@ def from_edges(
     if blocked or hybrid or skew_table:
         _note_phase("layouts", time.perf_counter() - _t_layouts)
 
+    ring_rep = None
+    if ring_shards:
+        with _phase("ring_layout"):
+            from p2pnetwork_tpu.parallel import ringlayout
+
+            ring_rep = ringlayout.build(senders, receivers, n_nodes, n_pad,
+                                        ring_shards)
+
     src_eid = src_offsets = None
     max_out_span = 0
     if source_csr:
@@ -1351,6 +1374,7 @@ def from_edges(
         layout_perm=(None if layout_perm is None
                      else jnp.asarray(layout_perm)),
         layout_inv=None if layout_inv is None else jnp.asarray(layout_inv),
+        ring=ring_rep,
     )
 
 

@@ -53,6 +53,7 @@ DEFAULT_SOURCES = (
     "ops/skew.py",
     "ops/bitset.py",
     "ops/frontier.py",
+    "parallel/ringlayout.py",
     "native/graphcore.cpp",
     "native/__init__.py",
 )

@@ -106,6 +106,7 @@ class JaxSimNode(Node):
         self.sim_peer: Optional[SimPeer] = None
         self.sim_mesh = None
         self.sim_sharded = None
+        self._shard_keep = None
         self._sim_rng: Optional[str] = None
         self._sim_key: Optional[jax.Array] = None
         self._sim_adaptive_k = 0
@@ -148,6 +149,14 @@ class JaxSimNode(Node):
         halo exchange ('ppermute', 'pallas' or 'auto', parallel/auto.py);
         both backends are bit-identical.
 
+        On a mesh the node keeps the pristine ``ShardedGraph`` of its
+        last attach: attaching the same graph object again with the same
+        mesh, ``layout`` and ``adaptive_k`` reuses it
+        (``sharded.ShardKeep``; churn since then made new ones, so the run
+        starts from the attach-time topology) and builds only the
+        protocol's initial state and any ``dynamic_edges`` region. Any
+        other graph or flag shards afresh.
+
         The key and the initial state are built under a ``sim_attach``
         span; their host seconds add to ``sim_attach_seconds_total``.
         """
@@ -186,19 +195,25 @@ class JaxSimNode(Node):
             self._sim_rng = rng
             self._sim_adaptive_k = adaptive_k
             self._sim_comm = comm
+            # The previous attach's topology and state leave the chips
+            # before a new shard comes.
+            self.sim_sharded = self.sim_state = None
             if mesh is not None:
                 from p2pnetwork_tpu.parallel import sharded
 
+                if self._shard_keep is None:
+                    self._shard_keep = sharded.ShardKeep()
                 sg = sharded.shard_graph(graph, mesh, mxu=layout == "mxu",
                                          hybrid=layout == "hybrid",
-                                         source_csr=adaptive_k > 0)
+                                         source_csr=adaptive_k > 0,
+                                         keep=self._shard_keep)
                 if dynamic_edges:
                     sg = sharded.with_capacity(sg, dynamic_edges)
                 self.sim_sharded = sg
                 self.sim_state = sharded.init_state(sg, protocol,
                                                     self._sim_key)
             else:
-                self.sim_sharded = None
+                self._shard_keep = None
                 self.sim_state = protocol.init(graph, self._sim_key)
         telemetry.default_registry().counter(
             "sim_attach_seconds_total",
